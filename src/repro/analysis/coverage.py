@@ -10,7 +10,7 @@ jaxpr:
 1. Trace the step under :func:`repro.core.marker.check_tagging`, so
    every ``Check.diff()`` comparison leaves an ``abft_check_sink``
    equation in the trace (see ``core/marker.py``).
-2. Flatten the ClosedJaxpr recursively — pjit, custom_jvp/vjp, scan,
+2. Flatten the ClosedJaxpr recursively — jit, custom_jvp/vjp, scan,
    while, cond sub-jaxprs are walked with *alias* edges tying inner
    binders to outer operands (scan carries additionally loop back), so
    dataflow is tracked precisely across call boundaries instead of
@@ -50,7 +50,7 @@ from repro.core.marker import CHECK_SINK
 # primitives that never carry payload dataflow we care about tracing
 # through sub-jaxprs specially; everything else with a jaxpr param gets
 # the conservative fallback
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "remat", "remat2",
+_CALL_PRIMS = ("jit", "closed_call", "core_call", "remat", "remat2",
                "checkpoint", "custom_jvp_call", "custom_vjp_call",
                "custom_vjp_call_jaxpr")
 
@@ -75,7 +75,7 @@ class OpSite:
     name: str                 # primitive or kernel name
     out_shape: Tuple[int, ...]
     provenance: str           # "file:line (fn)"
-    path: str                 # jaxpr nesting path, e.g. "pjit/pjit"
+    path: str                 # jaxpr nesting path, e.g. "jit/jit"
     checked: bool = False
     granularities: Tuple[str, ...] = ()
 

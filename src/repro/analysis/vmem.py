@@ -214,15 +214,16 @@ class PallasVmemEstimate:
 
 
 def _block_nbytes(block_shape, aval) -> int:
-    """Bytes of one pipeline block: the BlockSpec's block shape (mapped
-    axes contribute 1) at the operand dtype; a None mapping means the
-    whole operand is resident."""
+    """Bytes of one pipeline block: the BlockSpec's block shape at the
+    operand dtype; a None mapping means the whole operand is resident.
+    Traced block dims are ``Blocked(block_size=...)`` (and kin); squeezed
+    or ``None`` dims contribute 1."""
     itemsize = getattr(getattr(aval, "dtype", None), "itemsize", 4)
     if block_shape is None:
         shape = tuple(getattr(aval, "shape", ()) or ())
     else:
-        shape = tuple(1 if (d is None or isinstance(d, type(None))) else int(d)
-                      for d in block_shape)
+        sizes = (getattr(d, "block_size", d) for d in block_shape)
+        shape = tuple(d if isinstance(d, int) else 1 for d in sizes)
     n = 1
     for d in shape:
         n *= int(d)
@@ -282,7 +283,7 @@ def pallas_call_vmem_bytes(eqn: Any, *,
 def jaxpr_vmem_report(closed_jaxpr: Any, *,
                       budget: int = FUSED_VMEM_BUDGET
                       ) -> List[PallasVmemEstimate]:
-    """Walk a ClosedJaxpr (recursing through pjit/scan/etc. sub-jaxprs)
+    """Walk a ClosedJaxpr (recursing through jit/scan/etc. sub-jaxprs)
     and statically estimate every ``pallas_call`` found."""
     from repro.analysis.coverage import iter_eqns
 

@@ -32,7 +32,7 @@ import threading
 from typing import Iterator, Tuple
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 from jax.interpreters import ad, batching, mlir
 
 Array = jax.Array

@@ -40,6 +40,8 @@ from .backends import AggregationBackend, make_backend
 Array = jax.Array
 Params = Any
 
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass
 class Graph:
@@ -106,11 +108,14 @@ def gcn_layer(bk: AggregationBackend, h: Array, w: Array, cfg: ABFTConfig,
             h_out, chk = fused
             checks = [] if chk is None else [chk]
             return (h_out, checks, None) if return_x else (h_out, checks)
-    x = h @ w
+    # full-precision f32 dots: on TPU, XLA's default runs an f32 dot as one
+    # bf16 pass, and X and the eq.-5 column would then round apart far
+    # enough to flag clean layers
+    x = jnp.matmul(h, w, precision=_EXACT)
     if not cfg.enabled:
         h_out, _ = bk.aggregate(x, None)
         return (h_out, [], x) if return_x else (h_out, [])
-    x_r = h.astype(cfg.dtype) @ w_r
+    x_r = jnp.matmul(h.astype(cfg.dtype), w_r, precision=_EXACT)
     h_out, chk = bk.aggregate(x, x_r)
     if cfg.mode == "split":
         # the backend owns the split check's granularity: generic
@@ -226,12 +231,14 @@ def gcn_forward(params: Params, graph: Graph, cfg: ABFTConfig, *,
 
 
 def gcn_apply(params: Params, graph: Graph, cfg: ABFTConfig, *,
-              backend: Optional[str] = None, partition=None,
+              backend=None, partition=None,
               **backend_opts) -> Tuple[Array, ABFTReport]:
     """The engine entry point: logits + one replicated ABFTReport.
 
     ``backend`` is ``"dense" | "bcoo" | "block_ell"`` (inferred from the
-    adjacency operand when omitted); ``partition`` a
+    adjacency operand when omitted) or an already-built
+    :class:`AggregationBackend` (``make_backend``), whose counters then
+    outlive the call; ``partition`` a
     :class:`~repro.engine.sharded.Partition` for stripe-sharded block-ELL
     aggregation (per-shard partial checks psum into this same report).
     """

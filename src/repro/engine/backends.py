@@ -215,10 +215,13 @@ class DenseBackend(AggregationBackend):
             col_checksum(self.s, cfg.dtype) if cfg.enabled else None)
 
     def aggregate(self, x, x_r):
-        h_out = jnp.matmul(self.s, x)
+        # full precision: TPU's default f32 dot is one bf16 pass, which
+        # rounds the two sides of the check apart
+        exact = jax.lax.Precision.HIGHEST
+        h_out = jnp.matmul(self.s, x, precision=exact)
         if x_r is None:
             return h_out, None
-        pred = jnp.einsum("...k,...k->...", self.s_c, x_r)
+        pred = jnp.einsum("...k,...k->...", self.s_c, x_r, precision=exact)
         return h_out, Check(predicted=pred, actual=_total(h_out, self.cfg),
                             granularity=self.granularity)
 
@@ -341,8 +344,16 @@ class BlockEllBackend(AggregationBackend):
         elif partition is not None:
             s = pad_block_rows(s, partition.n_shards)
         self.bell = s
-        from repro.kernels.spmm_abft.ops import device_block_ell
-        self.cols, self.vals = device_block_ell(s)
+        if partition is None:
+            from repro.kernels.spmm_abft.ops import device_block_ell
+            self.cols, self.vals = device_block_ell(s)
+        else:
+            # each shard's stripes go straight to their own device, never
+            # all through the default one
+            from repro.launch.mesh import GraphShardingRules
+            rules = GraphShardingRules(partition.mesh, partition.axis)
+            self.cols, self.vals = jax.device_put(
+                (s.block_cols, s.values), rules.block_ell_shardings())
 
     def _set_granularity(self, granularity: Optional[str], *, packed: bool):
         if granularity is None:

@@ -23,7 +23,6 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.core.abft import Check
@@ -95,12 +94,12 @@ def sharded_spmm_abft(bell, cols: Array, vals: Array, x: Array,
         actual = jax.lax.psum(sums_l.sum(), axis)
         return out_l, pred, actual
 
-    shard = shard_map(
+    shard = jax.shard_map(
         body, mesh=partition.mesh,
         in_specs=(rules.stripe_spec(), rules.tile_spec(),
                   rules.activation_spec(), rules.activation_spec()),
         out_specs=_check_specs(rules, granularity),
-        check_rep=False)  # pallas_call has no replication rule
+        check_vma=False)  # pallas_call has no replication rule
     out, pred, actual = shard(cols, vals, xp, xrp)
     out = trim_output(bell, out, g)
     if not want_check:
@@ -149,13 +148,13 @@ def sharded_gcn_fused(bell, cols: Array, vals: Array, h: Array, w: Array,
         actual = jax.lax.psum(sums_l.sum(), axis)
         return out_l, pred, actual
 
-    shard = shard_map(
+    shard = jax.shard_map(
         body, mesh=partition.mesh,
         in_specs=(rules.stripe_spec(), rules.tile_spec(),
                   rules.activation_spec(), rules.activation_spec(),
                   rules.activation_spec()),
         out_specs=_check_specs(rules, granularity),
-        check_rep=False)  # pallas_call has no replication rule
+        check_vma=False)  # pallas_call has no replication rule
     out, pred, actual = shard(cols, vals, hp, wp, wrp)
     out = trim_output(bell, out, g)
     if not want_check:

@@ -61,7 +61,7 @@ from repro.engine.backends import BlockEllBackend
 from repro.kernels.runtime import resolve_interpret
 from repro.engine.batching import GraphBatch, PackedGraphs, \
     graph_pack_stats, pack_graphs
-from repro.runtime import ABFTGuard
+from repro.runtime import ABFTGuard, GuardRefused
 
 log = logging.getLogger(__name__)
 
@@ -197,9 +197,10 @@ class PackedRunner:
                  fused_layer: bool = False, granularity: str = "graph",
                  fused_network: bool = False,
                  vmem_budget: Optional[int] = None,
-                 inject=None):
+                 inject=None, interpret: Optional[bool] = None):
         self.params, self.cfg = params, cfg
         self.block_g = block_g
+        self.interpret = resolve_interpret(interpret)
         self.fused_layer = fused_layer
         self.fused_network = fused_network
         self.vmem_budget = vmem_budget
@@ -221,6 +222,7 @@ class PackedRunner:
                 self._warn_fallbacks(pb)
             self._steps[key] = make_packed_serve_step(
                 self.params, self.cfg, pb.n_slots, block_g=self.block_g,
+                interpret=self.interpret,
                 fused_layer=self.fused_layer,
                 fused_network=self.fused_network,
                 vmem_budget=self.vmem_budget,
@@ -372,7 +374,8 @@ class PackedRunner:
 
         def sretry(out, metrics):
             return surgical_stripe_retry(pb, self.params, self.cfg, out,
-                                         metrics, block_g=self.block_g)
+                                         metrics, block_g=self.block_g,
+                                         interpret=self.interpret)
         return sretry
 
     def slot_retry_fn(self, pb: PackedGraphs):
@@ -384,7 +387,8 @@ class PackedRunner:
 
         def slretry(out, metrics):
             return surgical_slot_retry(pb, self.params, self.cfg, out,
-                                       metrics, block_g=self.block_g)
+                                       metrics, block_g=self.block_g,
+                                       interpret=self.interpret)
         return slretry
 
 
@@ -555,7 +559,10 @@ class StreamingEngine:
     a mismatch refolds + rebuilds the jitted steps.  ``inject=`` is the
     level-0 chaos hook (the kernel accumulator fault) — degraded levels
     are always built clean, which is what lets the ladder actually
-    recover from a sticky backend fault in the e2e tests.
+    recover from a sticky backend fault in the e2e tests.  ``interpret=``
+    pins Pallas interpret mode for every kernel the engine runs, retries
+    included (``None`` resolves through
+    :func:`~repro.kernels.runtime.resolve_interpret`).
     """
 
     def __init__(self, params, cfg: ABFTConfig, rungs: RungTable, *,
@@ -574,6 +581,7 @@ class StreamingEngine:
                  hang_timeout: Optional[float] = None,
                  checkpoint_dir: Optional[str] = None,
                  selfcheck_interval: Optional[int] = None,
+                 interpret: Optional[bool] = None,
                  clock: Callable[[], float] = time.perf_counter):
         if oversize_policy not in ("singleton", "reject"):
             raise ValueError(f"oversize_policy {oversize_policy!r} not in "
@@ -591,6 +599,7 @@ class StreamingEngine:
         self.vmem_budget = vmem_budget
         self._block_g = rungs.block if block_g is None else block_g
         self._inject = inject
+        self.interpret = resolve_interpret(interpret)
         # the backend degrade ladder: level 0 is the configured backend
         # (and the only level carrying the chaos inject hook); fusion
         # levels fall back to the two-pass packed path, which falls back
@@ -682,7 +691,8 @@ class StreamingEngine:
                 spec["fused_layer"], self.granularity,
                 fused_network=spec["fused_network"],
                 vmem_budget=self.vmem_budget,
-                inject=self._inject if level == 0 else None)
+                inject=self._inject if level == 0 else None,
+                interpret=self.interpret)
         return self._level_runners[level]
 
     def _at_last_level(self) -> bool:
@@ -726,7 +736,7 @@ class StreamingEngine:
         keeps serving with nothing dropped.  Raises only when the ladder
         is exhausted — the dense terminal backend failed too."""
         if self._at_last_level():
-            raise RuntimeError(
+            raise GuardRefused(
                 f"stream: backend ladder exhausted at "
                 f"{self._ladder[-1]['name']!r} — {reason}")
         self.failovers += 1
@@ -1034,10 +1044,12 @@ class StreamingEngine:
                 out, metrics = self.guard.adjudicate(
                     inf["out"], inf["metrics"], dense_retry_fn(step, b),
                     replay=(step, (jnp.asarray(b.s), jnp.asarray(b.h0))))
-        except RuntimeError as err:
+        except GuardRefused as err:
             # the guard refused to adopt this batch on this backend
             # (persistent fault, restore path exhausted or absent):
-            # degrade the ladder and re-dispatch the same requests there
+            # degrade the ladder and re-dispatch the same requests there.
+            # Any other error — a device fault surfacing at this first
+            # host sync — propagates instead of degrading to dense.
             if self.watchdog is not None:
                 self.watchdog.stop()
             self._failover(inf, str(err))

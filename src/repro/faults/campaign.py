@@ -56,7 +56,7 @@ from repro.faults.model import (
     sweep_models,
 )
 from repro.faults.selfcheck import verify_s_c, verify_w_r
-from repro.runtime import ABFTGuard, GuardConfig
+from repro.runtime import ABFTGuard, GuardConfig, GuardRefused
 
 
 # ---------------------------------------------------------------------------
@@ -173,7 +173,7 @@ def _adjudicate(guard: ABFTGuard, out, gflags, grel, pb, rerun) -> bool:
     try:
         guard.adjudicate(out, metrics, retry)
         return False
-    except RuntimeError:
+    except GuardRefused:
         return True
 
 
@@ -190,7 +190,7 @@ def _adjudicate_dense(guard: ABFTGuard, outs, flags, rels, rerun) -> bool:
     try:
         guard.adjudicate(outs, metrics, retry)
         return False
-    except RuntimeError:
+    except GuardRefused:
         return True
 
 
@@ -511,7 +511,7 @@ def run_lm_experiment(model: FaultModel, *, prefill, decode, master, fold,
                         decode(params, st, tk, pos, pop()),
                     state["params"], states, ref_tokens[t - 1],
                     prompt_len + t - 1)
-        except RuntimeError:
+        except GuardRefused:
             # guard refused to verify after max_restores — eviction
             # advice.  Recover with a clean unguarded step so the
             # trajectory (decode states) can continue.

@@ -45,6 +45,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.analysis.vmem import network_vmem_bytes
+from repro.kernels.spmm_abft.kernel import f32_dot
+
+# Mosaic's default scoped-VMEM limit on v5e; a kernel whose working set
+# may exceed it must ask for more through ``vmem_limit_bytes``
+_SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024
+
+
+def _store_lane(ref, j, value):
+    """``ref[..., j] = value`` for a traced lane index ``j``, as a select
+    over the whole (1, ..., width) block: Mosaic has no dynamic-lane
+    scalar store into VMEM."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, ref.shape, ref.ndim - 1)
+    ref[...] = jnp.where(lane == j, value, ref[...])
+
 
 def _make_kernel(inject: Optional[Tuple[int, int, float]], with_check: bool,
                  with_slots: bool):
@@ -64,21 +79,21 @@ def _make_kernel(inject: Optional[Tuple[int, int, float]], with_check: bool,
 
         s = s_ref[0, 0]
         h = h_ref[...]
-        x = jnp.dot(h, w_ref[...], preferred_element_type=jnp.float32)
-        acc_ref[...] += jnp.dot(s, x, preferred_element_type=jnp.float32)
+        x = f32_dot(h, w_ref[...])
+        acc_ref[...] += f32_dot(s, x)
         if with_check:
             # the eq.-5 column, from its own dot so an MXU fault in x
             # cannot cancel — statically elided when checking is off
             # (mode="none" pays zero extra flops over an unchecked sweep)
-            xr = jnp.dot(h, wr_ref[...], preferred_element_type=jnp.float32)
-            ex_ref[...] += jnp.dot(s, xr, preferred_element_type=jnp.float32)
+            xr = f32_dot(h, wr_ref[...])
+            ex_ref[...] += f32_dot(s, xr)
 
         if inject is not None:
             ii, jj, delta = inject
 
             @pl.when((pl.program_id(0) == ii) & (j == jj))
             def _inject():
-                acc_ref[0, 0] += jnp.float32(delta)
+                acc_ref[0:1, 0:1] += jnp.float32(delta)
 
         if with_slots:
             # telescoped running sums, recorded AFTER the inject hook: slot
@@ -86,14 +101,14 @@ def _make_kernel(inject: Optional[Tuple[int, int, float]], with_check: bool,
             # an accumulator fault between two recordings lands in exactly
             # one slot's corner while the final value stays Σ acc — per-slot
             # sums built from tile products alone would miss it
-            sacts_ref[0, j] = jnp.sum(acc_ref[...])
-            spreds_ref[0, j] = jnp.sum(ex_ref[...])
+            _store_lane(sacts_ref, j, jnp.sum(acc_ref[...]))
+            _store_lane(spreds_ref, j, jnp.sum(ex_ref[...]))
 
         @pl.when(j == nj - 1)
         def _epilogue():
             acc = acc_ref[...]
             out_ref[...] = acc.astype(out_ref.dtype)
-            sums_ref[0, 0] = jnp.sum(acc)
+            sums_ref[...] = jnp.full(sums_ref.shape, jnp.sum(acc))
             extra_ref[...] = ex_ref[...]
 
     return _kernel
@@ -114,7 +129,12 @@ def gcn_fused_kernel(block_cols: jax.Array, values: jax.Array, h: jax.Array,
     Returns (out [nbm*bm, G], stripe_sums [nbm, 1], extra [nbm*bm, 1]);
     ``with_slots=True`` appends the telescoped per-slot running sums
     (slot_acts [nbm, width], slot_preds [nbm, width]) for slot-granular
-    corners (``ops.slot_check_corners``)."""
+    corners (``ops.slot_check_corners``).
+
+    Per-stripe outputs leave the kernel with a unit axis before the last
+    ([nbm, 1, 1], [nbm, 1, width]) so every block's last two dims span the
+    array, as Mosaic's (8, 128) tiling rule requires; they are reshaped
+    back here."""
     nbm, width, bm, bk = values.shape
     k, f = h.shape
     fw, g = w.shape
@@ -122,19 +142,18 @@ def gcn_fused_kernel(block_cols: jax.Array, values: jax.Array, h: jax.Array,
 
     out_specs = [
         pl.BlockSpec((bm, g), lambda i, j, cols: (i, 0)),
-        pl.BlockSpec((1, 1), lambda i, j, cols: (i, 0)),
+        pl.BlockSpec((1, 1, 1), lambda i, j, cols: (i, 0, 0)),
         pl.BlockSpec((bm, 1), lambda i, j, cols: (i, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((nbm * bm, g), h.dtype),
-        jax.ShapeDtypeStruct((nbm, 1), jnp.float32),
+        jax.ShapeDtypeStruct((nbm, 1, 1), jnp.float32),
         jax.ShapeDtypeStruct((nbm * bm, 1), jnp.float32),
     ]
     if with_slots:
-        out_specs += [pl.BlockSpec((1, width), lambda i, j, cols: (i, 0)),
-                      pl.BlockSpec((1, width), lambda i, j, cols: (i, 0))]
-        out_shape += [jax.ShapeDtypeStruct((nbm, width), jnp.float32),
-                      jax.ShapeDtypeStruct((nbm, width), jnp.float32)]
+        out_specs += [pl.BlockSpec((1, 1, width),
+                                   lambda i, j, cols: (i, 0, 0))] * 2
+        out_shape += [jax.ShapeDtypeStruct((nbm, 1, width), jnp.float32)] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -151,12 +170,13 @@ def gcn_fused_kernel(block_cols: jax.Array, values: jax.Array, h: jax.Array,
             pltpu.VMEM((bm, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    res = pl.pallas_call(
         _make_kernel(inject, with_check, with_slots),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
     )(block_cols, values, h, w, wr)
+    return [r.reshape(nbm, -1) if r.ndim == 3 else r for r in res]
 
 
 # ---------------------------------------------------------------------------
@@ -197,24 +217,24 @@ def _make_network_kernel(n_layers: int, bm: int,
             h = jnp.where(ell == 0, h0_ref[...], h_res)
         else:
             h = h0_ref[...]
-        x = jnp.dot(h, w, preferred_element_type=jnp.float32)
-        acc_ref[...] += jnp.dot(s, x, preferred_element_type=jnp.float32)
+        x = f32_dot(h, w)
+        acc_ref[...] += f32_dot(s, x)
         if with_check:
-            xr = jnp.dot(h, wr_ref[0], preferred_element_type=jnp.float32)
-            ex_ref[...] += jnp.dot(s, xr, preferred_element_type=jnp.float32)
+            xr = f32_dot(h, wr_ref[0])
+            ex_ref[...] += f32_dot(s, xr)
 
         if inject is not None:
             il, ii, jj, delta = inject
 
             @pl.when((ell == il) & (i == ii) & (j == jj))
             def _inject():
-                acc_ref[0, 0] += jnp.float32(delta)
+                acc_ref[0:1, 0:1] += jnp.float32(delta)
 
         # telescoped per-slot running sums (see _make_kernel): the slot
         # corners certify each layer pre-activation, exactly as the
         # sequential per-layer sweep would
-        tacts_ref[0, 0, j] = jnp.sum(acc_ref[...])
-        tpreds_ref[0, 0, j] = jnp.sum(ex_ref[...])
+        _store_lane(tacts_ref, j, jnp.sum(acc_ref[...]))
+        _store_lane(tpreds_ref, j, jnp.sum(ex_ref[...]))
 
         last = j == nj - 1
 
@@ -292,13 +312,15 @@ def gcn_network_kernel(block_cols: jax.Array, values: jax.Array,
     out_specs = [
         pl.BlockSpec((bm, p),
                      lambda l, i, j, cols: (jnp.where(l == nl - 1, i, 0), 0)),
-        pl.BlockSpec((1, 1, width), lambda l, i, j, cols: (l, i, 0)),
-        pl.BlockSpec((1, 1, width), lambda l, i, j, cols: (l, i, 0)),
+        # [L, nbm, 1, width]: the unit axis lets a one-stripe block span
+        # the array's last two dims (Mosaic's tiling rule)
+        pl.BlockSpec((1, 1, 1, width), lambda l, i, j, cols: (l, i, 0, 0)),
+        pl.BlockSpec((1, 1, 1, width), lambda l, i, j, cols: (l, i, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((k, p), h0.dtype),
-        jax.ShapeDtypeStruct((nl, nbm, width), jnp.float32),
-        jax.ShapeDtypeStruct((nl, nbm, width), jnp.float32),
+        jax.ShapeDtypeStruct((nl, nbm, 1, width), jnp.float32),
+        jax.ShapeDtypeStruct((nl, nbm, 1, width), jnp.float32),
     ]
     if stash_acts:
         out_specs.append(pl.BlockSpec((1, bm, p),
@@ -331,9 +353,17 @@ def gcn_network_kernel(block_cols: jax.Array, values: jax.Array,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    # the pipeline double-buffers even the constant-index W slab, which the
+    # VMEM model counts once: twice the model bounds the real working set
+    need = 2 * network_vmem_bytes([p] * (nl + 1), bm, k, block_g=p)
+    params = (pltpu.CompilerParams(vmem_limit_bytes=need)
+              if need > _SCOPED_VMEM_DEFAULT else None)
+    out, tacts, tpreds, acts = pl.pallas_call(
         _make_network_kernel(n_layers, bm, inject, with_check, stash_acts),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=params,
         interpret=interpret,
     )(block_cols, values, h0, ws, wrs)
+    return (out, tacts.reshape(nl, nbm, width),
+            tpreds.reshape(nl, nbm, width), acts)

@@ -34,6 +34,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def f32_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """MXU matmul at full f32 precision.  Mosaic's default runs an f32 dot
+    as one bf16 pass, which on a TPU v5e left full-Cora logits 9e-5 (0.3 %
+    of the largest logit) off a float32 reference."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
 def _make_kernel(inject: Optional[Tuple[int, int, float]]):
     def _kernel(cols_ref, s_ref, x_ref, xr_ref, out_ref, sums_ref, extra_ref,
                 acc_ref, ex_ref):
@@ -46,10 +54,8 @@ def _make_kernel(inject: Optional[Tuple[int, int, float]]):
             ex_ref[...] = jnp.zeros_like(ex_ref)
 
         s = s_ref[0, 0]
-        acc_ref[...] += jnp.dot(s, x_ref[...],
-                                preferred_element_type=jnp.float32)
-        ex_ref[...] += jnp.dot(s, xr_ref[...],
-                               preferred_element_type=jnp.float32)
+        acc_ref[...] += f32_dot(s, x_ref[...])
+        ex_ref[...] += f32_dot(s, xr_ref[...])
 
         if inject is not None:
             # same accumulator-upset hook as the fused kernel: perturbs one
@@ -59,13 +65,13 @@ def _make_kernel(inject: Optional[Tuple[int, int, float]]):
 
             @pl.when((pl.program_id(0) == ii) & (j == jj))
             def _inject():
-                acc_ref[0, 0] += jnp.float32(delta)
+                acc_ref[0:1, 0:1] += jnp.float32(delta)
 
         @pl.when(j == nj - 1)
         def _epilogue():
             acc = acc_ref[...]
             out_ref[...] = acc.astype(out_ref.dtype)
-            sums_ref[0, 0] = jnp.sum(acc)
+            sums_ref[...] = jnp.full(sums_ref.shape, jnp.sum(acc))
             extra_ref[...] = ex_ref[...]
 
     return _kernel
@@ -80,7 +86,11 @@ def spmm_abft_kernel(block_cols: jax.Array, values: jax.Array, x: jax.Array,
     to bk / lane multiples and to cover max(block_cols)+1 stripes.
     ``inject=(stripe, slot, delta)`` perturbs one accumulator element
     mid-sweep (CI fault hook).
-    Returns (out [nbm*bm, G], stripe_sums [nbm, 1], extra [nbm*bm, 1])."""
+    Returns (out [nbm*bm, G], stripe_sums [nbm, 1], extra [nbm*bm, 1]).
+
+    The stripe sums leave the kernel as [nbm, 1, 1] with (1, 1, 1) blocks:
+    Mosaic requires a block's last two dims to be (8, 128)-divisible or
+    to span the array, which a (1, 1) block over [nbm, 1] does not."""
     nbm, width, bm, bk = values.shape
     k, g = x.shape
     assert k % bk == 0 and xr.shape == (k, 1)
@@ -95,7 +105,7 @@ def spmm_abft_kernel(block_cols: jax.Array, values: jax.Array, x: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((bm, g), lambda i, j, cols: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, cols: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j, cols: (i, 0, 0)),
             pl.BlockSpec((bm, 1), lambda i, j, cols: (i, 0)),
         ],
         scratch_shapes=[
@@ -103,13 +113,14 @@ def spmm_abft_kernel(block_cols: jax.Array, values: jax.Array, x: jax.Array,
             pltpu.VMEM((bm, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out, sums, extra = pl.pallas_call(
         _make_kernel(inject),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((nbm * bm, g), x.dtype),
-            jax.ShapeDtypeStruct((nbm, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nbm, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((nbm * bm, 1), jnp.float32),
         ],
         interpret=interpret,
     )(block_cols, values, x, xr)
+    return out, sums.reshape(nbm, 1), extra

@@ -59,6 +59,7 @@ from repro.engine.streaming import (
     make_serve_step,
     packed_step_args,
 )
+from repro.kernels.runtime import use_compile_cache
 from repro.runtime import ABFTGuard
 
 Batch = Union[GraphBatch, PackedGraphs]
@@ -206,6 +207,7 @@ def serve(batches: Sequence[Batch], params, cfg: ABFTConfig,
 
 
 def main(argv: Optional[Sequence[str]] = None) -> dict:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graphs", type=int, default=64)
     ap.add_argument("--batch", type=int, default=8)

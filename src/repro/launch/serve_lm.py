@@ -40,7 +40,7 @@ import numpy as np
 from repro.configs import get_config, smoke_config
 from repro.core.abft import ABFTConfig
 from repro.engine.lm import LMEngine
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import resolve_interpret, use_compile_cache
 from repro.models.transformer import model_decode, model_prefill
 
 
@@ -65,6 +65,7 @@ def _clean_reference(engine: LMEngine, tokens, n_new: int):
 
 
 def main(argv: Optional[Sequence[str]] = None) -> dict:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
     ap.add_argument("--batch", type=int, default=2)

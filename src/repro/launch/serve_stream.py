@@ -30,9 +30,11 @@ import jax
 from repro.core.abft import ABFTConfig
 from repro.core.gcn import init_gcn
 from repro.engine import StreamingEngine, plan_rungs, synth_graph_stream
+from repro.kernels.runtime import resolve_interpret, use_compile_cache
 
 
 def main(argv: Optional[Sequence[str]] = None) -> dict:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graphs", type=int, default=200,
                     help="synthetic stream length (requests)")
@@ -79,7 +81,7 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
 
     n_lo, n_hi = (int(v) for v in args.nodes.split(","))
     cfg = ABFTConfig(mode=args.abft, threshold=1e-3, relative=True)
-    interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret()
     print(f"=== serve_stream: {args.graphs} requests, slots {args.slots}, "
           f"block {args.block}, abft={args.abft} "
           f"({jax.default_backend()}{', interpret' if interpret else ''}) "
@@ -105,7 +107,8 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
         fused_network=args.fused_network,
         vmem_budget=args.vmem_budget,
         granularity=args.check_granularity,
-        keep_logits=False)
+        keep_logits=False,
+        interpret=interpret)
     engine.warmup()
 
     results = []

@@ -1,2 +1,2 @@
-from .abft_guard import ABFTGuard, GuardConfig  # noqa: F401
+from .abft_guard import ABFTGuard, GuardConfig, GuardRefused  # noqa: F401
 from .watchdog import StragglerWatchdog  # noqa: F401

@@ -71,6 +71,14 @@ import numpy as np
 log = logging.getLogger(__name__)
 
 
+class GuardRefused(RuntimeError):
+    """The guard declined to adopt a step's result: a persistent fault with
+    no replay to escalate to, no ``restore_fn``, or every restore+replay
+    still flagged.  Serving layers catch exactly this to degrade their
+    backend; any other error (a device fault surfacing at the first host
+    sync among them) propagates."""
+
+
 @dataclasses.dataclass
 class GuardConfig:
     max_retries: int = 2
@@ -377,7 +385,7 @@ class ABFTGuard:
                 "the doomed retry tiers, escalating to restore",
                 self.steps, sorted(persistent)[:4])
             if replay is None:
-                raise RuntimeError(
+                raise GuardRefused(
                     f"ABFT: persistent fault at {sorted(persistent)[:4]} "
                     f"and no replay=(step_fn, args) to escalate to — "
                     f"evict or degrade this backend")
@@ -496,7 +504,7 @@ class ABFTGuard:
                 return out, self._adopt(metrics)
         self._recent.append(True)
         if replay is None:
-            raise RuntimeError(
+            raise GuardRefused(
                 "ABFT: persistent per-graph fault and no replay=(step_fn, "
                 "args) to escalate to — the dispatching caller must keep "
                 "the step closure alive until adjudication")
@@ -520,7 +528,7 @@ class ABFTGuard:
         returned state is ignored.  Never returns flagged metrics; raises
         after ``max_restores`` failed restore+replay rounds."""
         if self.restore_fn is None:
-            raise RuntimeError("ABFT: persistent fault and no restore_fn "
+            raise GuardRefused("ABFT: persistent fault and no restore_fn "
                                "given")
         for r in range(1, self.cfg.max_restores + 1):
             if self.cfg.restore_backoff > 0:
@@ -547,7 +555,7 @@ class ABFTGuard:
             if not bool(np.asarray(flag).any()):
                 log.warning("ABFT: replay after restore %d verified clean", r)
                 return out, metrics
-        raise RuntimeError(
+        raise GuardRefused(
             f"ABFT: step still flagged after {self.cfg.max_restores} "
             f"restore+replay attempt(s) — refusing to adopt unverified "
             f"state (suspect persistent hardware fault; evict this host)")

@@ -135,7 +135,7 @@ class TestFalsifiability:
 
         m = analyze_step(jax.jit(fixture), jnp.ones((3, 6)))
         assert m.n_unchecked == 1
-        assert "pjit" in m.unchecked_ops[0].path
+        assert "jit" in m.unchecked_ops[0].path
 
 
 # ---------------------------------------------------------------------------

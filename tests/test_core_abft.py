@@ -1,4 +1,6 @@
 """Unit + property tests for the ABFT core (the paper's contribution)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -116,7 +118,7 @@ def test_gcn_layer_detects_output_corruption(mode):
     # corrupt one element of the final output -> actual checksum diverges
     bad = h_out.at[3, 5].add(100.0)
     actual_bad = bad.sum()
-    chk_bad = checks[-1]._replace(actual=actual_bad)
+    chk_bad = dataclasses.replace(checks[-1], actual=actual_bad)
     assert bool(chk_bad.flag(cfg))
 
 

@@ -18,12 +18,13 @@ Acceptance properties:
   (e) the engine's periodic self-check catches a corrupted eq.-5 fold
       mid-stream, refolds, rebuilds its steps, and the stream continues.
 """
+import jax
 import numpy as np
 import pytest
 
 from repro.core.abft import ABFTConfig
 from repro.engine import StreamingEngine, plan_rungs, synth_graph_stream
-from repro.runtime import ABFTGuard, GuardConfig
+from repro.runtime import ABFTGuard, GuardConfig, GuardRefused
 from repro.runtime.watchdog import StragglerWatchdog
 
 FEAT, HIDDEN, CLASSES = 8, 16, 4
@@ -140,6 +141,26 @@ def test_sticky_fault_degrades_backend_and_keeps_serving(fusion, tmp_path):
     # post-degrade traffic is clean: later results carry no flags
     tail = [r for r in results if r.rid >= 8]
     assert tail and not any(r.flag for r in tail)
+
+
+class _DeviceFaultGuard(ABFTGuard):
+    """A device error surfacing at adjudication, the first host sync."""
+
+    def adjudicate(self, *args, **kwargs):
+        raise jax.errors.JaxRuntimeError("INTERNAL: simulated device fault")
+
+
+def test_device_error_at_adjudication_is_not_swallowed():
+    """Only the guard's own refusal degrades the ladder: a device error is
+    a RuntimeError too, but it must propagate instead of quietly moving
+    the stream onto the dense backend."""
+    assert issubclass(GuardRefused, RuntimeError)
+    stream = _stream(4)
+    engine = _engine(stream, guard=_DeviceFaultGuard())
+    with pytest.raises(jax.errors.JaxRuntimeError, match="simulated"):
+        _serve_all(engine, stream)
+    assert engine.degrades == 0 and engine.failovers == 0
+    assert engine.stats()["active_backend"] == "two-pass"
 
 
 def test_dense_fallback_matches_packed_logits():

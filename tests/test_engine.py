@@ -210,8 +210,11 @@ def test_batched_serving_matches_per_graph():
                 == 0.0
 
 
-def test_serve_gcn_driver_smoke(capsys):
+def test_serve_gcn_driver_smoke(capsys, monkeypatch):
     from repro.launch.serve_gcn import main
+    # keep the test process off the persistent compile cache
+    monkeypatch.setattr("repro.launch.serve_gcn.use_compile_cache",
+                        lambda: None)
     stats = main(["--graphs", "8", "--batch", "4", "--buckets", "32,64",
                   "--nodes", "16,56", "--feat", "8", "--hidden", "8",
                   "--classes", "3"])

@@ -1,5 +1,6 @@
 """Checkpoint / optimizer / data / runtime substrate tests."""
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -185,3 +186,29 @@ def test_straggler_watchdog():
     wd.start(); time.sleep(0.05)
     assert wd.stop() is True
     assert wd.events == 1
+
+
+# ---------------------------------------------------------------------------
+# persistent compile cache placement
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_placed_from_outside_is_left_alone(monkeypatch,
+                                                         tmp_path):
+    from repro.kernels.runtime import use_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prev   # nothing set
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    from repro.kernels.runtime import use_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        path = use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        checkout = pathlib.Path(__file__).resolve().parents[1]
+        assert pathlib.Path(path) == checkout / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -39,7 +39,7 @@ from repro.engine import (
     pad_graph,
     synth_graph_stream,
 )
-from repro.runtime import ABFTGuard, GuardConfig
+from repro.runtime import ABFTGuard, GuardConfig, GuardRefused
 
 
 def _stream(n_graphs=3, seed=1, feat=8, n_lo=20, n_hi=70):
@@ -211,12 +211,12 @@ def test_guard_restore_bounded_and_raises_unverified():
 
     g = ABFTGuard(GuardConfig(max_retries=0, max_restores=2),
                   restore_fn=lambda: None)
-    with pytest.raises(RuntimeError, match="still flagged after 2"):
+    with pytest.raises(GuardRefused, match="still flagged after 2"):
         g.run_step(always_bad, 0)
     assert g.restores == 2
 
     g2 = ABFTGuard(GuardConfig(max_retries=0))    # no restore_fn at all
-    with pytest.raises(RuntimeError, match="no restore_fn"):
+    with pytest.raises(GuardRefused, match="no restore_fn"):
         g2.run_step(always_bad, 0)
 
 
@@ -427,8 +427,11 @@ def test_serve_block_ell_matches_dense_graph_for_graph():
     assert packed["graphs_per_sec"] > 0
 
 
-def test_serve_gcn_driver_block_ell_smoke(capsys):
+def test_serve_gcn_driver_block_ell_smoke(capsys, monkeypatch):
     from repro.launch.serve_gcn import main
+    # keep the test process off the persistent compile cache
+    monkeypatch.setattr("repro.launch.serve_gcn.use_compile_cache",
+                        lambda: None)
 
     stats = main(["--graphs", "8", "--batch", "4", "--backend", "block_ell",
                   "--block", "16", "--nodes", "16,56", "--feat", "8",

@@ -53,7 +53,7 @@ from repro.engine.streaming import (
     next_pow2,
     packed_step_args,
 )
-from repro.runtime import ABFTGuard, GuardConfig
+from repro.runtime import ABFTGuard, GuardConfig, GuardRefused
 
 FEAT, HIDDEN, CLASSES = 4, 4, 3
 BLOCK = 8
@@ -403,7 +403,7 @@ def test_guard_adjudicate_without_replay_raises_on_escalation():
 
     g = ABFTGuard(GuardConfig(max_retries=1), restore_fn=lambda: None)
     out, m = step()
-    with pytest.raises(RuntimeError, match="no replay"):
+    with pytest.raises(GuardRefused, match="no replay"):
         g.adjudicate(out, m, bad_retry)
 
 

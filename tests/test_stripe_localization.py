@@ -499,8 +499,11 @@ def test_serve_stripe_granularity_matches_graph():
               granularity="stripe")
 
 
-def test_serve_gcn_driver_stripe_smoke(capsys):
+def test_serve_gcn_driver_stripe_smoke(capsys, monkeypatch):
     from repro.launch.serve_gcn import main
+    # keep the test process off the persistent compile cache
+    monkeypatch.setattr("repro.launch.serve_gcn.use_compile_cache",
+                        lambda: None)
 
     stats = main(["--graphs", "6", "--batch", "3", "--backend", "block_ell",
                   "--block", "16", "--nodes", "16,48", "--feat", "8",

@@ -1,0 +1,116 @@
+"""Compile rehearsal: every main-path Pallas kernel, compiled (not
+interpreted) for a described TPU v5e chip at the paper's published widths.
+
+Interpret mode never enforces Mosaic's rules — (8, 128) block tiling,
+scalar stores into VMEM, the scoped VMEM limit — so the CPU parity tests
+cannot see a kernel the chip's compiler would refuse.  These tests run the
+TPU compiler that ships with JAX against shapes only: nothing executes, so
+they say nothing about results or speed.
+
+The topology is described inside a fixture, never at import time: only one
+process at a time may load the TPU library, and pytest-xdist workers all
+import this module.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.datasets import STATS
+from repro.kernels.gcn_fused.kernel import gcn_fused_kernel, gcn_network_kernel
+from repro.kernels.spmm_abft.kernel import spmm_abft_kernel
+
+BLOCK = 128
+NETWORK_STRIPES = 8      # a packed serving batch the network kernel admits
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _lanes(n: int) -> int:
+    return -(-n // BLOCK) * BLOCK
+
+
+def _widths(name: str):
+    """(stripes, ELL width, lane-padded feature / hidden widths) of the
+    full published graph; synthetic ER graphs fill every column block, so
+    the ELL width is the stripe count."""
+    st = STATS[name]
+    nbm = -(-st.nodes // BLOCK)
+    return nbm, nbm, _lanes(st.feat_dim), _lanes(st.hidden)
+
+
+def _compile(kernel, sharding, shapes, **static):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in shapes]
+    compiled = jax.jit(functools.partial(kernel, interpret=False, **static)
+                       ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()   # a Mosaic kernel ran
+
+
+def _block_ell(nbm, width):
+    return [((nbm, width), jnp.int32),
+            ((nbm, width, BLOCK, BLOCK), jnp.float32)]
+
+
+DATASETS = ["cora", "pubmed"]
+
+
+@pytest.mark.parametrize("inject", [None, (1, 2, 5.0)],
+                         ids=["clean", "inject"])
+@pytest.mark.parametrize("name", DATASETS)
+def test_spmm_abft_compiles(one_chip, name, inject):
+    nbm, width, _f, g = _widths(name)
+    _compile(spmm_abft_kernel, one_chip,
+             _block_ell(nbm, width) + [((nbm * BLOCK, g), jnp.float32),
+                                       ((nbm * BLOCK, 1), jnp.float32)],
+             inject=inject)
+
+
+@pytest.mark.parametrize("variant", [
+    {},
+    {"with_check": False},
+    {"with_slots": True},
+    {"inject": (1, 2, 5.0)},
+], ids=["check", "nocheck", "slots", "inject"])
+@pytest.mark.parametrize("name", DATASETS)
+def test_gcn_fused_compiles(one_chip, name, variant):
+    nbm, width, f, g = _widths(name)
+    _compile(gcn_fused_kernel, one_chip,
+             _block_ell(nbm, width) + [((nbm * BLOCK, f), jnp.float32),
+                                       ((f, g), jnp.float32),
+                                       ((f, 1), jnp.float32)],
+             **variant)
+
+
+@pytest.mark.parametrize("variant", [
+    {},
+    {"with_check": False},
+    {"inject": (1, 1, 2, 5.0), "stash_acts": True},
+], ids=["check", "nocheck", "inject-stash"])
+@pytest.mark.parametrize("name", DATASETS)
+def test_gcn_network_compiles(one_chip, name, variant):
+    _nbm, _width, f, _g = _widths(name)
+    nbm = width = NETWORK_STRIPES
+    p = f                      # the shared width is the widest layer
+    _compile(gcn_network_kernel, one_chip,
+             _block_ell(nbm, width) + [((nbm * BLOCK, p), jnp.float32),
+                                       ((2, p, p), jnp.float32),
+                                       ((2, p, 1), jnp.float32)],
+             **variant)
