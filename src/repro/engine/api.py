@@ -20,6 +20,7 @@ their historical entry points as thin shims over this engine.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, List, Optional, Tuple
 
@@ -34,6 +35,8 @@ from repro.core.abft import (
     resolve_w_r,
     summarize,
 )
+
+from repro.runtime.spans import span
 
 from .backends import AggregationBackend, make_backend
 
@@ -111,12 +114,16 @@ def gcn_layer(bk: AggregationBackend, h: Array, w: Array, cfg: ABFTConfig,
     # full-precision f32 dots: on TPU, XLA's default runs an f32 dot as one
     # bf16 pass, and X and the eq.-5 column would then round apart far
     # enough to flag clean layers
-    x = jnp.matmul(h, w, precision=_EXACT)
+    with span("gcn.combine"):
+        x = jnp.matmul(h, w, precision=_EXACT)
     if not cfg.enabled:
-        h_out, _ = bk.aggregate(x, None)
+        with span("gcn.aggregate"):
+            h_out, _ = bk.aggregate(x, None)
         return (h_out, [], x) if return_x else (h_out, [])
-    x_r = jnp.matmul(h.astype(cfg.dtype), w_r, precision=_EXACT)
-    h_out, chk = bk.aggregate(x, x_r)
+    with span("gcn.check_column"):
+        x_r = jnp.matmul(h.astype(cfg.dtype), w_r, precision=_EXACT)
+    with span("gcn.aggregate"):
+        h_out, chk = bk.aggregate(x, x_r)
     if cfg.mode == "split":
         # the backend owns the split check's granularity: generic
         # check_matmul scalars, or per-graph corners on the packed path
@@ -217,13 +224,14 @@ def gcn_forward(params: Params, graph: Graph, cfg: ABFTConfig, *,
     h_layers: List[Array] = []
     x_layers: List[Optional[Array]] = []
     for i, layer in enumerate(layers):
-        h_layers.append(h)
-        w_r = wrs[i] if wrs is not None else layer.get("w_r")
-        h_out, cs, x = gcn_layer(bk, h, layer["w"], cfg, w_r=w_r,
-                                 return_x=True)
-        checks.extend(cs)
-        x_layers.append(x)
-        h = jax.nn.relu(h_out) if i < len(layers) - 1 else h_out
+        with span("gcn.layer", layer=i):
+            h_layers.append(h)
+            w_r = wrs[i] if wrs is not None else layer.get("w_r")
+            h_out, cs, x = gcn_layer(bk, h, layer["w"], cfg, w_r=w_r,
+                                     return_x=True)
+            checks.extend(cs)
+            x_layers.append(x)
+            h = jax.nn.relu(h_out) if i < len(layers) - 1 else h_out
     if return_intermediates:
         return ((h, checks, tuple(h_layers), tuple(x_layers)) if return_x
                 else (h, checks, tuple(h_layers)))
@@ -242,6 +250,12 @@ def gcn_apply(params: Params, graph: Graph, cfg: ABFTConfig, *,
     :class:`~repro.engine.sharded.Partition` for stripe-sharded block-ELL
     aggregation (per-shard partial checks psum into this same report).
     """
-    logits, checks = gcn_forward(params, graph, cfg, backend=backend,
-                                 partition=partition, **backend_opts)
-    return logits, summarize(checks, cfg)
+    # the span holds the report too: a forward's verdict is part of it
+    with span("gcn.forward", mode=cfg.mode):
+        logits, checks = gcn_forward(params, graph, cfg, backend=backend,
+                                     partition=partition, **backend_opts)
+        # with the check off there is nothing to reduce: no span
+        with (span("gcn.summarize") if cfg.enabled
+              else contextlib.nullcontext()):
+            report = summarize(checks, cfg)
+    return logits, report

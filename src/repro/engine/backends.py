@@ -39,6 +39,7 @@ from repro.core.abft import (GRANULARITIES, ABFTConfig, Check, CheckedOp,
                              _total)
 from repro.core.checksum import col_checksum
 from repro.kernels.runtime import resolve_interpret
+from repro.runtime.spans import span
 
 Array = jax.Array
 
@@ -450,23 +451,28 @@ class BlockEllBackend(AggregationBackend):
         if self.inject is not None and self._layer_calls == self.inject[0]:
             inject = tuple(self.inject[1:])
         self._layer_calls += 1
-        if self.segments is not None:
-            return gcn_fused_packed(self.cols, self.vals, h, w, w_r,
-                                    self.segments, num_segments=self.n_slots,
-                                    block_g=self.block_g,
-                                    granularity=self.granularity,
-                                    interpret=self.interpret, inject=inject)
-        if self.partition is None:
-            return gcn_fused_layer(self.bell, h, w, w_r,
-                                   block_g=self.block_g,
-                                   granularity=self.granularity,
-                                   interpret=self.interpret, inject=inject,
-                                   _staged=(self.cols, self.vals))
-        from .sharded import sharded_gcn_fused
-        return sharded_gcn_fused(self.bell, self.cols, self.vals, h, w, w_r,
-                                 self.partition, block_g=self.block_g,
-                                 granularity=self.granularity,
-                                 interpret=self.interpret)
+        with span("gcn.aggregate"):
+            if self.segments is not None:
+                return gcn_fused_packed(self.cols, self.vals, h, w, w_r,
+                                        self.segments,
+                                        num_segments=self.n_slots,
+                                        block_g=self.block_g,
+                                        granularity=self.granularity,
+                                        interpret=self.interpret,
+                                        inject=inject)
+            if self.partition is None:
+                return gcn_fused_layer(self.bell, h, w, w_r,
+                                       block_g=self.block_g,
+                                       granularity=self.granularity,
+                                       interpret=self.interpret,
+                                       inject=inject,
+                                       _staged=(self.cols, self.vals))
+            from .sharded import sharded_gcn_fused
+            return sharded_gcn_fused(self.bell, self.cols, self.vals, h, w,
+                                     w_r, self.partition,
+                                     block_g=self.block_g,
+                                     granularity=self.granularity,
+                                     interpret=self.interpret)
 
     def network(self, h0, ws, wrs, cfg, *, stash=False):
         """Whole-network fusion (``kernels/gcn_fused``'s network kernel):
@@ -499,19 +505,21 @@ class BlockEllBackend(AggregationBackend):
             return NotImplemented
         self.network_hits += 1
         self._layer_calls += len(ws)     # the sweep consumed every layer
-        if self.segments is not None:
-            return gcn_network_packed(self.cols, self.vals, h0, ws, wrs,
-                                      self.segments,
-                                      num_segments=self.n_slots,
-                                      block_g=self.block_g,
-                                      granularity=self.granularity,
-                                      interpret=self.interpret,
-                                      inject=self.inject, stash_acts=stash)
-        return gcn_network_layer(self.bell, h0, ws, wrs,
-                                 block_g=self.block_g,
-                                 granularity=self.granularity,
-                                 interpret=self.interpret,
-                                 inject=self.inject, stash_acts=stash)
+        with span("gcn.layer", layer="network"):
+            if self.segments is not None:
+                return gcn_network_packed(self.cols, self.vals, h0, ws, wrs,
+                                          self.segments,
+                                          num_segments=self.n_slots,
+                                          block_g=self.block_g,
+                                          granularity=self.granularity,
+                                          interpret=self.interpret,
+                                          inject=self.inject,
+                                          stash_acts=stash)
+            return gcn_network_layer(self.bell, h0, ws, wrs,
+                                     block_g=self.block_g,
+                                     granularity=self.granularity,
+                                     interpret=self.interpret,
+                                     inject=self.inject, stash_acts=stash)
 
     def combination_check(self, h, w, x, cfg, *, w_r=None):
         if self.granularity in ("stripe", "slot"):

@@ -62,6 +62,7 @@ from repro.kernels.runtime import resolve_interpret
 from repro.engine.batching import GraphBatch, PackedGraphs, \
     graph_pack_stats, pack_graphs
 from repro.runtime import ABFTGuard, GuardRefused
+from repro.runtime.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -642,10 +643,11 @@ class StreamingEngine:
         self.keep_logits = keep_logits
         self.clock = clock
         self._bins: Dict[Rung, _OpenBin] = {}
-        # one in-flight batch, tagged by dispatch kind:
-        #   {"kind": "packed", "runner", "pb", "out", "metrics", "rids"}
+        # one in-flight batch, tagged by dispatch kind and sequence number:
+        #   {"kind": "packed", "runner", "pb", "out", "metrics", "rids",
+        #    "seq"}
         #   {"kind": "dense", "step", "batch", "items", "out", "metrics",
-        #    "rids"}
+        #    "rids", "seq"}
         self._inflight: Optional[Dict[str, Any]] = None
         self._inflight_t: Optional[float] = None
         self._results: Dict[int, RequestResult] = {}
@@ -670,6 +672,10 @@ class StreamingEngine:
         self.dense_dispatches = 0
         self.hang_flushes = 0
         self.selfcheck_repairs = 0
+        # seals by the branch that sealed the bin, and bytes staged by
+        # packed_step_args for the step and for the guard's replay
+        self.seals = {"full": 0, "deadline": 0, "drain": 0}
+        self.staged_bytes = {"step": 0, "replay": 0}
         self._runner_for(0)           # eager level-0 runner (warmup path)
 
     # -- backend ladder ----------------------------------------------------
@@ -759,10 +765,12 @@ class StreamingEngine:
         every folded w_r bitwise; a mismatch means the CHECK path is
         corrupt (every verdict a lie), so refold and rebuild the jitted
         steps that baked the stale fold in at trace time."""
-        if self._selfcheck is None:
+        if (self._selfcheck is None
+                or not self._selfcheck.due(self.batches_dispatched)):
             return
-        bad = self._selfcheck.maybe_check(self.params,
-                                          self.batches_dispatched)
+        with span("stream.selfcheck"):
+            bad = self._selfcheck.maybe_check(self.params,
+                                              self.batches_dispatched)
         if bad:
             log.error("stream: check-path self-check tripped on layer(s) "
                       "%s — refolding w_r and rebuilding serve steps", bad)
@@ -833,7 +841,7 @@ class StreamingEngine:
         b = self._bins.get(rung)
         if b is not None and (len(b.items) >= rung.n_slots
                               or b.load + stripes > rung.stripe_cap):
-            self._seal(rung, now)
+            self._seal(rung, now, "full")
             b = None
         if b is None:
             b = _OpenBin(rung=rung, items=[], first_enqueue=now)
@@ -841,7 +849,7 @@ class StreamingEngine:
         b.items.append((rid, s, h0))
         b.load += stripes
         if len(b.items) >= rung.n_slots or b.load >= rung.stripe_cap:
-            self._seal(rung, now)
+            self._seal(rung, now, "full")
         return rid
 
     def pump(self, now: Optional[float] = None) -> None:
@@ -867,7 +875,7 @@ class StreamingEngine:
         ALL completed results collected since the last ``take_results``."""
         now = self.clock() if now is None else now
         for rung in list(self._bins):
-            self._seal(rung, now)
+            self._seal(rung, now, "drain")
         self._drain_inflight()
         return self.take_results()
 
@@ -914,33 +922,55 @@ class StreamingEngine:
                          width_cap=wq * next_pow2(-(-width // wq)),
                          indices=[rid])
         self.singleton_dispatches += 1
-        self._dispatch(pb, [rid], now)
+        self._dispatch(pb, [rid], now, kind="singleton")
 
     def _sweep_deadlines(self, now: float) -> None:
         if self.flush_deadline is None:
             return
         for rung, b in list(self._bins.items()):
             if b.items and now - b.first_enqueue >= self.flush_deadline:
-                self._seal(rung, now)
+                self._seal(rung, now, "deadline")
 
-    def _seal(self, rung: Rung, now: float) -> None:
+    def _seal(self, rung: Rung, now: float, cause: str) -> None:
+        """Seal the rung's open bin and dispatch it.  ``cause`` names the
+        branch that sealed it: ``full`` (slots or stripe capacity),
+        ``deadline`` (the flush deadline) or ``drain``."""
         b = self._bins.pop(rung, None)
         if b is None or not b.items:
             return
-        # pack on the host FIRST (overlaps the in-flight batch's device
-        # execution), then adjudicate the previous batch, then dispatch
-        rids = [rid for rid, _, _ in b.items]
-        items = [(s, h0) for _, s, h0 in b.items]
-        if self._active_dense():
-            self._dispatch_dense(items, rids, now)
-            return
-        pb = pack_graphs(items,
-                         block=self.rungs.block, n_slots=rung.n_slots,
-                         stripe_multiple=self.rungs.stripe_multiple,
-                         width_multiple=self.rungs.width_multiple,
-                         stripe_cap=rung.stripe_cap,
-                         width_cap=rung.width_cap, indices=rids)
-        self._dispatch(pb, rids, now)
+        self.seals[cause] += 1
+        # the span's batch is the sequence number this batch's dispatch
+        # takes, unless a failover re-dispatches another batch first
+        with span("stream.seal", batch=self.batches_dispatched, cause=cause,
+                  graphs=len(b.items)):
+            # pack on the host FIRST (overlaps the in-flight batch's device
+            # execution), then adjudicate the previous batch, then dispatch
+            rids = [rid for rid, _, _ in b.items]
+            items = [(s, h0) for _, s, h0 in b.items]
+            if self._active_dense():
+                self._dispatch_dense(items, rids, now)
+                return
+            with span("stream.pack"):
+                pb = pack_graphs(items,
+                                 block=self.rungs.block,
+                                 n_slots=rung.n_slots,
+                                 stripe_multiple=self.rungs.stripe_multiple,
+                                 width_multiple=self.rungs.width_multiple,
+                                 stripe_cap=rung.stripe_cap,
+                                 width_cap=rung.width_cap, indices=rids)
+            self._dispatch(pb, rids, now)
+
+    def _stage(self, pb: PackedGraphs,
+               purpose: str) -> Tuple[jax.Array, ...]:
+        """``packed_step_args(pb)``, counted in ``staged_bytes[purpose]``:
+        ``step`` for the dispatch, ``replay`` for the operands the guard
+        keeps for a restore."""
+        host = (pb.bell.block_cols, pb.bell.values, pb.stripe_graph, pb.h0)
+        nbytes = sum(a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+                     for a in host)
+        self.staged_bytes[purpose] += nbytes
+        with span("stream.stage", purpose=purpose, bytes=nbytes):
+            return packed_step_args(pb)
 
     def _drain_inflight(self) -> None:
         """Resolve the in-flight batch AND any batch a failover re-
@@ -952,7 +982,7 @@ class StreamingEngine:
             self._resolve_inflight()
 
     def _dispatch(self, pb: PackedGraphs, rids: List[int],
-                  now: float) -> None:
+                  now: float, kind: str = "packed") -> None:
         self._drain_inflight()
         if self._active_dense():
             # the resolution above degraded the ladder to its terminal
@@ -963,7 +993,10 @@ class StreamingEngine:
         self._maybe_selfcheck()
         runner = self.runner
         step = runner.step_for(pb)
-        out, metrics = step(*packed_step_args(pb))   # async dispatch
+        args = self._stage(pb, "step")
+        seq = self.batches_dispatched
+        with span("stream.dispatch", batch=seq, kind=kind):
+            out, metrics = step(*args)   # async dispatch
         t = self.clock()
         for rid in rids:
             self._results[rid].t_dispatch = t
@@ -971,7 +1004,8 @@ class StreamingEngine:
         for key, n in runner.fusion_counts(pb).items():
             setattr(self, key, getattr(self, key) + n)
         self._inflight = {"kind": "packed", "runner": runner, "pb": pb,
-                          "out": out, "metrics": metrics, "rids": rids}
+                          "out": out, "metrics": metrics, "rids": rids,
+                          "seq": seq}
         self._inflight_t = t
         if self.watchdog is not None:
             self.watchdog.start()
@@ -1005,7 +1039,9 @@ class StreamingEngine:
             self._dense_step_fn = make_serve_step(self.params, self.cfg)
         self._dense_shapes.add((pad, bucket, feat))
         step = self._dense_step_fn
-        out, metrics = step(jnp.asarray(b.s), jnp.asarray(b.h0))
+        seq = self.batches_dispatched
+        with span("stream.dispatch", batch=seq, kind="dense"):
+            out, metrics = step(jnp.asarray(b.s), jnp.asarray(b.h0))
         t = self.clock()
         for rid in rids:
             self._results[rid].t_dispatch = t
@@ -1013,7 +1049,7 @@ class StreamingEngine:
         self.dense_dispatches += 1
         self._inflight = {"kind": "dense", "step": step, "batch": b,
                           "items": list(items), "out": out,
-                          "metrics": metrics, "rids": rids}
+                          "metrics": metrics, "rids": rids, "seq": seq}
         self._inflight_t = t
         if self.watchdog is not None:
             self.watchdog.start()
@@ -1024,6 +1060,10 @@ class StreamingEngine:
         inf = self._inflight
         self._inflight = None
         self._inflight_t = None
+        with span("stream.resolve", batch=inf["seq"]):
+            self._adjudicate_inflight(inf)
+
+    def _adjudicate_inflight(self, inf: Dict[str, Any]) -> None:
         rids = inf["rids"]
         try:
             if inf["kind"] == "packed":
@@ -1038,7 +1078,7 @@ class StreamingEngine:
                     inf["out"], inf["metrics"], runner.retry_fn(pb),
                     stripe_retry_fn=stripe_retry,
                     slot_retry_fn=slot_retry,
-                    replay=(step, packed_step_args(pb)))
+                    replay=(step, self._stage(pb, "replay")))
             else:
                 step, b = inf["step"], inf["batch"]
                 out, metrics = self.guard.adjudicate(
@@ -1100,22 +1140,25 @@ class StreamingEngine:
         """The deferred device->host flush: one bulk transfer per
         adjudicated batch instead of per-request ``float()``/slice syncs
         in the dispatch hot loop."""
-        for kind, out, grel, payload, batch in self._pending_mat:
-            out_np = np.asarray(out) if self.keep_logits else None  # abftlint: sync-ok
-            n_slots = (payload.n_slots if kind == "packed"
-                       else payload.s.shape[0])
-            grel_np = (np.zeros(n_slots, np.float32) if grel is None
-                       else np.asarray(grel, np.float32))  # abftlint: sync-ok
-            for k, res in batch:
-                res.max_rel = float(grel_np[k])  # abftlint: sync-ok (host array, stats flush)
-                if out_np is None:
-                    continue
-                if kind == "packed":
-                    o, n = payload.row_offsets[k], payload.n_nodes[k]
-                    res.logits = out_np[o:o + n].copy()
-                else:
-                    res.logits = out_np[k, :payload.n_nodes[k]].copy()
-        self._pending_mat = []
+        if not self._pending_mat:
+            return
+        with span("stream.materialize", batches=len(self._pending_mat)):
+            for kind, out, grel, payload, batch in self._pending_mat:
+                out_np = np.asarray(out) if self.keep_logits else None  # abftlint: sync-ok
+                n_slots = (payload.n_slots if kind == "packed"
+                           else payload.s.shape[0])
+                grel_np = (np.zeros(n_slots, np.float32) if grel is None
+                           else np.asarray(grel, np.float32))  # abftlint: sync-ok
+                for k, res in batch:
+                    res.max_rel = float(grel_np[k])  # abftlint: sync-ok (host array, stats flush)
+                    if out_np is None:
+                        continue
+                    if kind == "packed":
+                        o, n = payload.row_offsets[k], payload.n_nodes[k]
+                        res.logits = out_np[o:o + n].copy()
+                    else:
+                        res.logits = out_np[k, :payload.n_nodes[k]].copy()
+            self._pending_mat = []
 
     # -- accounting --------------------------------------------------------
 
@@ -1140,9 +1183,9 @@ class StreamingEngine:
         lat = np.asarray([r.latency for r in rs
                           if r.status == "served" and r.latency is not None])
         served = [r for r in rs if r.status == "served"]
-        span = ((max(r.t_verdict for r in served)
-                 - min(r.t_enqueue for r in served))
-                if served else 0.0)
+        elapsed = ((max(r.t_verdict for r in served)
+                    - min(r.t_enqueue for r in served))
+                   if served else 0.0)
         return {
             "submitted": self.submitted,
             "served": len(served),
@@ -1151,6 +1194,8 @@ class StreamingEngine:
                                      for r in rs),
             "flagged": sum(bool(r.flag) for r in served),
             "batches": self.batches_dispatched,
+            "seals": dict(self.seals),
+            "staged_bytes": dict(self.staged_bytes),
             "singleton_dispatches": self.singleton_dispatches,
             "compiles": self.compile_count,
             "rung_table_size": len(self.rungs),
@@ -1159,7 +1204,8 @@ class StreamingEngine:
             "latency_p99_ms": float(np.percentile(lat, 99) * 1e3)
             if lat.size else None,
             "latency_max_ms": float(lat.max() * 1e3) if lat.size else None,
-            "graphs_per_sec": len(served) / span if span > 0 else None,
+            "graphs_per_sec": (len(served) / elapsed if elapsed > 0
+                               else None),
             "guard_flags": self.guard.flags,
             "guard_retries": self.guard.retries,
             "fused_hits": self.fused_hits,

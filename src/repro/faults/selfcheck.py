@@ -93,8 +93,12 @@ class CheckPathSelfCheck:
         if self.interval < 1:
             raise ValueError("selfcheck interval must be >= 1")
 
+    def due(self, step: int) -> bool:
+        """Whether :meth:`maybe_check` runs a check at ``step``."""
+        return step % self.interval == 0
+
     def maybe_check(self, params, step: int) -> Optional[List[int]]:
-        if step % self.interval != 0:
+        if not self.due(step):
             return None
         self.checks_run += 1
         bad = verify_w_r(params, self.cfg)

@@ -7,12 +7,14 @@ through :func:`spmm_abft_auto`, which falls back to interpret mode off-TPU.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.abft import Check
+from repro.runtime.spans import span
 
 from .kernel import spmm_abft_kernel
 from .layout import BlockEll
@@ -102,11 +104,15 @@ def spmm_abft(bell: BlockEll, x: jax.Array, xr: Optional[jax.Array] = None,
     out, stripe_sums, extra = spmm_abft_kernel(cols, vals, xp, xrp,
                                                interpret=interpret,
                                                inject=inject)
-    if granularity == "stripe":
-        return trim_output(bell, out, g), stripe_check_corners(stripe_sums,
-                                                               extra)
-    return trim_output(bell, out, g), Check(predicted=extra[:n, 0].sum(),
-                                            actual=stripe_sums.sum())
+    out = trim_output(bell, out, g)
+    # the corners are the GCN check only where the caller carried the
+    # eq.-5 column; an unchecked layer passes none and drops them
+    with (span("gcn.corners") if xr is not None
+          else contextlib.nullcontext()):
+        if granularity == "stripe":
+            return out, stripe_check_corners(stripe_sums, extra)
+        return out, Check(predicted=extra[:n, 0].sum(),
+                          actual=stripe_sums.sum())
 
 
 def validate_packed_operands(vals: jax.Array, rows: int, name: str) -> None:

@@ -68,6 +68,8 @@ from typing import Any, Callable, Optional, Tuple
 
 import numpy as np
 
+from repro.runtime.spans import span
+
 log = logging.getLogger(__name__)
 
 
@@ -363,8 +365,15 @@ class ABFTGuard:
         activations over a sustained stream); the closures see the full
         metrics.
         """
+        with span("guard.adjudicate"):
+            return self._adjudicate(out, metrics, retry_fn,
+                                    stripe_retry_fn, slot_retry_fn, replay)
+
+    def _adjudicate(self, out, metrics, retry_fn, stripe_retry_fn,
+                    slot_retry_fn, replay):
         self.steps += 1
-        flags = np.array(metrics["abft_graph_flags"], dtype=bool).copy()
+        with span("guard.sync"):
+            flags = np.array(metrics["abft_graph_flags"], dtype=bool).copy()
         if not flags.any():
             self._recent.append(False)
             self._backoff_level = 0
@@ -404,7 +413,8 @@ class ABFTGuard:
             log.error("ABFT: step %d: %d slot corner(s) flagged; "
                       "attempting slot-surgical repair", self.steps,
                       int(slflags.sum()))
-            out2, sub = slot_retry_fn(out, metrics)
+            with span("guard.retry", tier="slot"):
+                out2, sub = slot_retry_fn(out, metrics)
             performed = int(sub.get("abft_stripes_recomputed", 0))
             self.retries += int(performed > 0)
             self.slot_retries += performed
@@ -429,7 +439,8 @@ class ABFTGuard:
             log.error("ABFT: step %d: %d stripe corner(s) flagged; "
                       "attempting surgical repair", self.steps,
                       int(sflags.sum()))
-            out2, sub = stripe_retry_fn(out, metrics)
+            with span("guard.retry", tier="stripe"):
+                out2, sub = stripe_retry_fn(out, metrics)
             performed = int(sub.get("abft_stripes_recomputed", 0))
             # retries counts re-executions PERFORMED: an escalation that
             # bailed before touching any stripe re-executed nothing
@@ -459,7 +470,8 @@ class ABFTGuard:
             log.error("ABFT: step %d: %d/%d graphs flagged; retrying them "
                       "(attempt %d)", self.steps, len(idx), len(flags),
                       attempt)
-            out, sub = retry_fn(out, idx)
+            with span("guard.retry", tier="graph"):
+                out, sub = retry_fn(out, idx)
             self.retries += 1
             self.graph_retries += len(idx)
             if "abft_rows_recomputed" in sub:
@@ -542,19 +554,22 @@ class ABFTGuard:
             log.error("ABFT: persistent fault; restore %d/%d + replay",
                       r, self.cfg.max_restores)
             self.restores += 1
-            restored = self.restore_fn()
-            replay_args = args
-            if adopt_state and restored is not None and args:
-                replay_args = (restored,) + tuple(args[1:])
-            out, metrics = step_fn(*replay_args)
-            # batch steps are only required to emit the per-graph vector
-            flag = metrics.get(
-                "abft_flag",
-                np.asarray(metrics["abft_graph_flags"]).any()
-                if "abft_graph_flags" in metrics else True)
-            if not bool(np.asarray(flag).any()):
-                log.warning("ABFT: replay after restore %d verified clean", r)
-                return out, metrics
+            with span("guard.retry", tier="restore"):
+                restored = self.restore_fn()
+                replay_args = args
+                if adopt_state and restored is not None and args:
+                    replay_args = (restored,) + tuple(args[1:])
+                out, metrics = step_fn(*replay_args)
+                # batch steps are only required to emit the per-graph
+                # vector
+                flag = metrics.get(
+                    "abft_flag",
+                    np.asarray(metrics["abft_graph_flags"]).any()
+                    if "abft_graph_flags" in metrics else True)
+                if not bool(np.asarray(flag).any()):
+                    log.warning("ABFT: replay after restore %d verified "
+                                "clean", r)
+                    return out, metrics
         raise GuardRefused(
             f"ABFT: step still flagged after {self.cfg.max_restores} "
             f"restore+replay attempt(s) — refusing to adopt unverified "
