@@ -25,6 +25,8 @@ implementations of this one protocol.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import threading
 from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
@@ -37,7 +39,7 @@ from .checksum import (
     row_checksum,
     total_checksum,
 )
-from .marker import tag_check
+from .marker import tag_check, tagging_enabled
 
 Array = jax.Array
 
@@ -111,22 +113,10 @@ class Check:
         return jnp.abs(p - a)
 
     def _scale(self) -> Array:
-        # the relative scale must stay FINITE: an overflowed output
-        # (actual = ±inf, e.g. a high exponent bit flip in a weight)
-        # would make tau*scale infinite and the comparison pass silently
-        # (inf <= inf).  Clamped to 1.0, the infinite divergence flags.
-        scale = jnp.maximum(1.0, jnp.abs(self.actual))
-        return jnp.where(jnp.isfinite(scale), scale, 1.0)
+        return _finite(jnp.maximum(1.0, jnp.abs(self.actual)))
 
     def flag(self, cfg: ABFTConfig) -> Array:
-        # NaN-safe: a NaN divergence (corrupted checksum path — a bit
-        # flip in w_r/s_c/the carried eq.-5 column propagating to pred)
-        # must FLAG.  ``d > tau`` is False for NaN, which would silently
-        # disable ABFT, so the comparison is negated: not (d <= tau).
-        d = self.diff()
-        if cfg.relative:
-            return jnp.any(~(d <= cfg.threshold * self._scale()))
-        return jnp.any(~(d <= cfg.threshold))
+        return jnp.any(_tripped(self.diff(), self._scale(), cfg))
 
     def elementwise(self, cfg: ABFTConfig) -> tuple[Array, Array]:
         """Per-element (flags, rel divergence) — the shared reduction core
@@ -134,8 +124,7 @@ class Check:
         like :meth:`flag`: a NaN comparison flags its element."""
         d = self.diff()
         scale = self._scale()
-        f = ~(d <= cfg.threshold * (scale if cfg.relative else 1.0))
-        return f, (d / scale).astype(jnp.float32)
+        return _tripped(d, scale, cfg), (d / scale).astype(jnp.float32)
 
     def tree_flatten(self):
         return (self.predicted, self.actual), self.granularity
@@ -143,6 +132,25 @@ class Check:
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(children[0], children[1], aux)
+
+
+def _finite(scale: Array) -> Array:
+    # the relative scale must stay FINITE: an overflowed output
+    # (actual = ±inf, e.g. a high exponent bit flip in a weight)
+    # would make tau*scale infinite and the comparison pass silently
+    # (inf <= inf).  Clamped to 1.0, the infinite divergence flags.
+    return jnp.where(jnp.isfinite(scale), scale, 1.0)
+
+
+def _tripped(d: Array, scale: Array, cfg: ABFTConfig) -> Array:
+    # NaN-safe: a NaN divergence (corrupted checksum path — a bit
+    # flip in w_r/s_c/the carried eq.-5 column propagating to pred)
+    # must FLAG.  ``d > tau`` is False for NaN, which would silently
+    # disable ABFT, so the comparison is negated: not (d <= tau).
+    # ``scale`` is the finite-clamped relative scale (see ``_finite``).
+    if cfg.relative:
+        return ~(d <= cfg.threshold * scale)
+    return ~(d <= cfg.threshold)
 
 
 class ABFTReport(NamedTuple):
@@ -497,18 +505,51 @@ def gcn_layer_sparse(s: Any, h: Array, w: Array, cfg: ABFTConfig,
 # Aggregation
 # ---------------------------------------------------------------------------
 
+# Traces of the compiled report body; read through report_traces().  Two
+# threads may trace at once, hence the lock.
+_report_traces = 0
+_report_traces_lock = threading.Lock()
+
+
+def report_traces() -> int:
+    """How many times :func:`summarize`'s compiled body has been traced.
+
+    One per distinct check structure (the checks' shapes, dtypes and
+    granularities, ``cfg``, the tagging state).  A count that rises with
+    every call means the jit cache misses and each report recompiles.
+    """
+    return _report_traces
+
+
 def summarize(checks: Sequence[Optional[Check]], cfg: ABFTConfig) -> ABFTReport:
-    """Reduce an arbitrary collection of checks to one replicated report."""
+    """Reduce an arbitrary collection of checks to one replicated report.
+
+    Runs as one compiled program per check structure (under an outer
+    trace it is a nested call, inlined into the caller's program)."""
     checks = [c for c in checks if c is not None]
     if not checks or not cfg.enabled:
         z = jnp.zeros((), jnp.float32)
         return ABFTReport(flag=jnp.zeros((), bool), max_rel=z, n_checks=z)
+    return _summarize_compiled(checks, cfg, tagging_enabled())
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "tagging"))
+def _summarize_compiled(checks: list[Check], cfg: ABFTConfig,
+                        tagging: bool) -> ABFTReport:
+    # ``tagging`` only keys the trace cache: ``Check.diff`` reads the same
+    # state while this traces.  Without it a lint trace would reuse an
+    # untagged trace and lose its check-sink equations.
+    del tagging
+    global _report_traces
+    with _report_traces_lock:
+        _report_traces += 1
     flags, rels, n = [], [], 0
     for c in checks:
         d = c.diff()
         scale = jnp.maximum(1.0, jnp.abs(c.actual))
+        # max_rel divides by the unclamped scale; only the flag clamps it
         rels.append(jnp.max(d / scale))
-        flags.append(c.flag(cfg))
+        flags.append(jnp.any(_tripped(d, _finite(scale), cfg)))
         n += int(np_size(c.actual))
     return ABFTReport(
         flag=jnp.stack(flags).any(),

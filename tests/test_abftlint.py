@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.analysis.coverage import analyze_jaxpr, analyze_step
+from repro.analysis.coverage import analyze_jaxpr, analyze_step, iter_eqns
 from repro.analysis.syncs import scan_source, scan_tree
 from repro.analysis.vmem import (
     FUSED_VMEM_BUDGET,
@@ -37,9 +37,10 @@ from repro.analysis.vmem import (
     jaxpr_vmem_report,
     lint_rung_table,
 )
-from repro.core.abft import ABFTConfig, check_matmul, summarize
+from repro.core.abft import (ABFTConfig, check_matmul, report_traces,
+                             summarize)
 from repro.core.gcn import init_gcn
-from repro.core.marker import check_tagging, tagging_enabled
+from repro.core.marker import CHECK_SINK, check_tagging, tagging_enabled
 from repro.engine import Graph, gcn_forward
 from repro.engine.api import fold_w_r
 from repro.engine.batching import pack_graphs
@@ -242,6 +243,39 @@ class TestMarkerInertness:
         closed = jax.make_jaxpr(fixture)(jnp.ones((3, 6)))
         m = analyze_jaxpr(closed)
         assert m.n_sinks == 0  # production traces carry no marker
+
+    def test_sink_survives_an_untagged_warm_call(self):
+        # summarize runs as a compiled body with a trace cache; a lint
+        # trace after an untagged call on the same check structure must
+        # still see the check-sink (its own config, so the warm call below
+        # is this structure's first trace in the process)
+        cfg = ABFTConfig(mode="fused", threshold=3.25e-3)
+        w = jnp.ones((6, 5))
+
+        def fixture(x):
+            y = x @ w
+            rep = summarize([check_matmul(x, w, y, cfg)], cfg)
+            return y, rep.flag, rep.max_rel
+
+        x = jnp.ones((3, 6))
+        before = report_traces()
+        fixture(x)                                  # eager, untagged
+        assert report_traces() == before + 1
+
+        with check_tagging():
+            closed = jax.make_jaxpr(fixture)(x)
+        sinks = [path for eqn, path in iter_eqns(closed)
+                 if eqn.primitive.name == CHECK_SINK]
+        assert sinks == ["/jit"]
+        m = analyze_jaxpr(closed)
+        assert (m.n_sinks, m.n_unchecked) == (1, 0)
+        assert m.n_checked >= 1
+        assert m.coverage == 1.0
+        assert m.sink_granularities == ("layer",)
+        # ...and the tagged trace does not leak into production traces
+        # (a fresh wrapper: make_jaxpr caches its own trace of ``fixture``)
+        untagged = jax.make_jaxpr(lambda x: fixture(x))(x)
+        assert analyze_jaxpr(untagged).n_sinks == 0
 
     def test_tagging_changes_no_numerics(self):
         rng = np.random.default_rng(1)

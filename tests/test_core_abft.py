@@ -12,6 +12,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import (
     ABFTConfig,
+    Check,
     check_chain,
     check_matmul,
     checked_matmul,
@@ -22,6 +23,7 @@ from repro.core import (
     predicted_matmul_checksum,
     summarize,
 )
+from repro.core.abft import report_traces
 from repro.core.checksum import col_checksum, row_checksum, total_checksum
 
 CFG = ABFTConfig(mode="fused", threshold=1e-3, relative=True)
@@ -164,6 +166,112 @@ def test_chain_check_batched():
     chk = check_chain([a, b, c], out, CFG)
     assert chk.predicted.shape == (2,)
     assert not bool(chk.flag(CFG))
+
+
+# ---------------------------------------------------------------------------
+# summarize: the compiled report reduction against plain numpy
+# ---------------------------------------------------------------------------
+
+# each granularity's corner shape: scalar, [n_graphs], [n_stripes],
+# [n_stripes, width]
+CHECK_SHAPES = {"layer": (), "graph": (5,), "stripe": (7,), "slot": (7, 3)}
+
+
+def _np_summary(pairs, cfg):
+    """Plain-numpy twin of ``summarize``: (flag, max_rel, n_checks)."""
+    flag, rels, n = False, [], 0
+    for p, a in pairs:
+        d = np.abs(p - a)
+        scale = np.maximum(np.float32(1.0), np.abs(a))
+        with np.errstate(invalid="ignore"):      # inf / inf is NaN
+            rels.append(np.max(d / scale))
+        if cfg.relative:
+            finite = np.where(np.isfinite(scale), scale, np.float32(1.0))
+            ok = d <= np.float32(cfg.threshold) * finite
+        else:
+            ok = d <= np.float32(cfg.threshold)
+        flag |= bool(np.any(~ok))
+        n += a.size
+    return (np.bool_(flag), np.float32(np.max(rels)), np.float32(n))
+
+
+def _corner_pair(shape, case, seed):
+    rng = np.random.default_rng(seed)
+    # |actual| on both sides of 1, so the relative scale is exercised
+    a = np.asarray(rng.normal(size=shape)
+                   * 10.0 ** rng.integers(-2, 3, size=shape), np.float32)
+    p = np.asarray(a * (1 + rng.normal(size=shape) * 1e-6), np.float32)
+    flat_p, flat_a = p.reshape(-1), a.reshape(-1)
+    last = flat_a.size - 1
+    if case == "drift":        # over tau on one element only
+        flat_p[last] = flat_a[last] + np.float32(0.5)
+    elif case == "nan_predicted":
+        flat_p[last] = np.nan
+    elif case == "inf_actual":
+        flat_a[last] = np.inf
+    elif case == "neg_inf_actual":
+        flat_a[last] = -np.inf
+    return p, a
+
+
+def _assert_report_equals(report, want):
+    flag, max_rel, n = jax.device_get(report)
+    assert (flag.dtype, max_rel.dtype, n.dtype) == (
+        np.bool_, np.float32, np.float32)
+    assert flag == want[0]
+    # bit for bit; NaN (an unbounded divergence) only has to be NaN
+    np.testing.assert_array_equal(max_rel, want[1])
+    if np.isfinite(want[1]):
+        assert max_rel.tobytes() == want[1].tobytes()
+    assert n.tobytes() == want[2].tobytes()
+
+
+@pytest.mark.parametrize("relative", [True, False])
+@pytest.mark.parametrize("case", ["clean", "drift", "nan_predicted",
+                                  "inf_actual", "neg_inf_actual"])
+@pytest.mark.parametrize("granularity", ["layer", "graph", "stripe", "slot",
+                                         "mixed"])
+def test_summarize_matches_numpy(granularity, case, relative):
+    cfg = ABFTConfig(mode="fused", threshold=1e-3, relative=relative)
+    grans = list(CHECK_SHAPES) if granularity == "mixed" else [granularity]
+    pairs = [_corner_pair(CHECK_SHAPES[g], case if i == len(grans) - 1
+                          else "clean", seed=i)
+             for i, g in enumerate(grans)]
+    checks = [Check(jnp.asarray(p), jnp.asarray(a), g)
+              for g, (p, a) in zip(grans, pairs)]
+    # a None in the list is dropped, as a mode=none layer's check is
+    report = summarize(checks[:1] + [None] + checks[1:], cfg)
+    _assert_report_equals(report, _np_summary(pairs, cfg))
+    assert bool(report.flag) == (case != "clean")
+
+
+@pytest.mark.parametrize("cfg, checks", [
+    (CFG, []),
+    (CFG, [None, None]),
+    (ABFTConfig(mode="none"),
+     [Check(jnp.float32(1.0), jnp.float32(9.0))]),
+], ids=["empty", "all_none", "mode_none"])
+def test_summarize_without_checks_reports_zero(cfg, checks):
+    before = report_traces()
+    report = summarize(checks, cfg)
+    _assert_report_equals(report, (np.bool_(False), np.float32(0.0),
+                                   np.float32(0.0)))
+    assert report_traces() == before       # nothing to compile
+
+
+def test_summarize_traces_once_per_check_structure():
+    cfg = ABFTConfig(mode="fused", threshold=2.5e-3)   # this test's own
+    checks = [Check(rand((7,), s), rand((7,), s), "stripe") for s in (1, 2)]
+    before = report_traces()
+    for _ in range(3):
+        summarize(checks, cfg)
+    assert report_traces() == before + 1
+    summarize(checks[:1], cfg)                 # another structure
+    summarize(checks, dataclasses.replace(cfg))   # an equal config
+    assert report_traces() == before + 2
+    # under an outer jit the body is a nested call of the same cache
+    jax.jit(lambda cs: summarize(cs, cfg))(checks)
+    assert report_traces() == before + 2
 
 
 # ---------------------------------------------------------------------------

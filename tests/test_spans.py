@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.abft import ABFTConfig
+from repro.core.abft import ABFTConfig, report_traces
 from repro.core.gcn import init_gcn
 from repro.engine import (Graph, StreamingEngine, fold_w_r, gcn_apply,
                           make_backend, plan_rungs, synth_graph_stream)
@@ -62,7 +62,8 @@ def _inside(inner, outer):
 # (a) one forward
 # ---------------------------------------------------------------------------
 
-def _forward_case(mode):
+def _forward_case(mode, threshold=1e-3):
+    """One staged graph and its checked forward (not yet called)."""
     rng = np.random.default_rng(3)
     n = 48
     s = (rng.random((n, n)) < 0.08).astype(np.float32)
@@ -70,7 +71,7 @@ def _forward_case(mode):
     s /= s.sum(axis=1, keepdims=True)
     bell = dense_to_block_ell(s, block_m=16, block_k=16)
     h0 = jnp.asarray(rng.normal(0, 0.5, (n, DIMS[0])).astype(np.float32))
-    cfg = ABFTConfig(mode=mode, threshold=1e-3, relative=True)
+    cfg = ABFTConfig(mode=mode, threshold=threshold, relative=True)
     params = fold_w_r(init_gcn(jax.random.PRNGKey(0), DIMS), cfg)
     bk = make_backend(bell, cfg, backend="block_ell", block_g=16,
                       interpret=True)
@@ -79,13 +80,14 @@ def _forward_case(mode):
         logits, report = gcn_apply(params, Graph(s=bell, h0=h0), cfg,
                                    backend=bk)
         return jax.device_get((logits, report.flag, report.max_rel))
-    forward()                                    # compile outside the trace
     return forward
 
 
 @pytest.mark.parametrize("mode", ["fused", "none"])
 def test_forward_span_tree(mode, tmp_path):
-    _, spans = _recorded(tmp_path, _forward_case(mode))
+    forward = _forward_case(mode)
+    forward()                                    # compile outside the trace
+    _, spans = _recorded(tmp_path, forward)
     names = [s[0] for s in spans]
     assert set(names) <= set(SPANS)
     fwd, = _named(spans, "gcn.forward")
@@ -107,6 +109,27 @@ def test_forward_span_tree(mode, tmp_path):
         # the corner reduction follows the aggregation's kernel, inside it
         assert all(_inside(c, a) for c, a in zip(
             _named(spans, "gcn.corners"), _named(spans, "gcn.aggregate")))
+
+
+def test_report_compiles_once_for_repeated_forwards(tmp_path):
+    # the report reduction is one compiled program per check structure:
+    # traced on the first forward, reused by every later one (a threshold
+    # of this test's own, so the first forward is the structure's first)
+    forward = _forward_case("fused", threshold=1.5e-3)
+    before = report_traces()
+    forward()
+    assert report_traces() == before + 1
+
+    def four_more():
+        return [forward() for _ in range(4)]
+
+    outs, spans = _recorded(tmp_path, four_more)
+    assert report_traces() == before + 1
+    assert not any(bool(flag) for _, flag, _ in outs)
+    forwards = _named(spans, "gcn.forward")
+    summaries = _named(spans, "gcn.summarize")
+    assert len(forwards) == len(summaries) == 4
+    assert all(_inside(s, f) for s, f in zip(summaries, forwards))
 
 
 # ---------------------------------------------------------------------------
