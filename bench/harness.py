@@ -1,12 +1,21 @@
 """One run of one benchmark cell, driven by ``BENCHMARK.json``.
 
-Nothing here names a cell, a configuration, a traffic mix or a metric.  The
-cell's entry names its configuration (a file of sizes under
+Nothing here names a cell, a configuration, a traffic mix, a driver or a
+metric.  The cell's entry names its configuration (a file of sizes under
 ``bench/configs``) and its traffic mix (a data file under
-``bench/traffic`` whose ``driver`` picks a load generator in
-``bench/drivers.py``); each metric is read by ``bench/metrics/<name>.py``,
-whose ``read(run)`` returns a number or ``None`` when there is nothing to
-read.
+``bench/traffic`` whose ``driver`` names a load generator,
+``bench/drivers/<driver>.py``, whose ``run(ctx)`` is loaded by path); each
+metric is read by ``bench/metrics/<name>.py``, whose ``read(run)`` returns a
+number or ``None`` when there is nothing to read.  The driver gets the
+cell's ``chips`` in ``ctx.chips``.
+
+So a new configuration, even of another model on a mesh of chips, comes as
+new files and new entries only: its sizes under ``bench/configs``; a
+traffic file that names its driver; the driver ``bench/drivers/<driver>.py``
+(shared pieces in ``bench/drivers/__init__.py``); its plain reference under
+``bench/references``; its metric readers under ``bench/metrics``; and its
+kernel's ``(ops, bytes)`` in a new module beside ``bench/cost.py``, which
+stays the yardstick of the GCN aggregation kernel.
 """
 from __future__ import annotations
 
@@ -55,13 +64,29 @@ def metrics_for(bench: dict, cell: str, traced: bool) -> List[dict]:
     return [m for m in group if cell in m.get("workloads", [cell])]
 
 
-def load_reader(name: str) -> Callable[[Run], Optional[float]]:
-    path = BENCH / "metrics" / f"{name}.py"
+def _load(path: pathlib.Path, prefix: str, name: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        prefix + name.replace(".", "_").replace("-", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(name: str) -> Callable[[Run], Optional[float]]:
+    return _load(BENCH / "metrics" / f"{name}.py", "bench_metric_", name).read
+
+
+def load_driver(name: str) -> Callable[[Any], None]:
+    """The ``run(ctx)`` of ``bench/drivers/<name>.py``.  A traffic file
+    names it, so the name is checked: no private helper (``_...``), no path
+    outside that directory, and a file that exists."""
+    path = BENCH / "drivers" / f"{name}.py"
+    if name.startswith("_") or "/" in name or "\\" in name:
+        raise ValueError(f"{name!r} is not a driver's name: {path} is a "
+                         "private helper or lies outside the drivers")
+    if not path.is_file():
+        raise FileNotFoundError(f"no driver {name!r}: {path} does not exist")
+    return _load(path, "bench_driver_", name).run
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -73,12 +98,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     config = load_json(ROOT / conf_entry["file"])
     traffic = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
     return measure(config, traffic, metrics_for(bench, workload, trace), seed,
-                   seconds, trace, **kw)
+                   seconds, trace, chips=cell["chips"], **kw)
 
 
 def measure(config: dict, traffic: dict, metric_specs: List[dict],
-            seed: int, seconds: float, trace: bool, *, t_start: float,
-            peak: dict, require_compiled: Callable[[bool], None],
+            seed: int, seconds: float, trace: bool, *, chips: int,
+            t_start: float, peak: dict,
+            require_compiled: Callable[[bool], None],
             trace_dir: Optional[str] = None) -> Dict[str, Any]:
     """Drive one run and return its result line (``device`` apart from
     what the run itself measured)."""
@@ -87,8 +113,8 @@ def measure(config: dict, traffic: dict, metric_specs: List[dict],
     ctx = drivers.Ctx(config=config, traffic=traffic, seed=seed,
                       seconds=seconds,
                       trace_dir=trace_dir if trace else None,
-                      require_compiled=require_compiled)
-    drivers.DRIVERS[traffic["driver"]](ctx)
+                      require_compiled=require_compiled, chips=chips)
+    load_driver(traffic["driver"])(ctx)
     run = Run(config=config, peak=peak, ctx=ctx,
               setup_time=ctx.setup_end - t_start)
     device: Dict[str, Any] = {"memory_peak_bytes": ctx.memory_peak_bytes}

@@ -57,8 +57,9 @@ def main(argv=None) -> int:
         tr["rate_per_s"] = rate
         ctx = drivers.Ctx(config=config, traffic=tr, seed=args.seed,
                           seconds=args.seconds, trace_dir=None,
-                          require_compiled=require_compiled)
-        drivers.DRIVERS[tr["driver"]](ctx)
+                          require_compiled=require_compiled,
+                          chips=cell["chips"])
+        harness.load_driver(tr["driver"])(ctx)
         lat = np.asarray(ctx.samples["latency_s"]) * 1e3
         half = len(lat) // 2
         late = np.asarray(ctx.samples["generator_late_s"]) * 1e3

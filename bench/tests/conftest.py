@@ -25,6 +25,20 @@ def peak():
     return harness.load_json(harness.BENCH / "peaks.json")["TPU v5 lite"]
 
 
+def cut_graph(graph, nodes, edges, feature_nnz):
+    """A configuration's ``graph`` section at other counts, with the same
+    topology; an LFR graph's largest degree and community sizes scaled
+    down to fit."""
+    out = {**graph, "nodes": nodes, "undirected_edges": edges,
+           "feature_nnz": feature_nnz}
+    if graph.get("topology") == "lfr":
+        out["max_degree"] = min(graph["max_degree"], nodes // 10)
+        out["community_max"] = min(graph["community_max"], nodes // 5)
+        out["community_min"] = min(graph["community_min"],
+                                   out["community_max"] // 6)
+    return out
+
+
 def tiny(bench, workload, traffic_name=None):
     """The cell's own configuration and traffic (or the named traffic
     file), cut to a CPU-sized graph (same layer structure, widths cut to
@@ -35,8 +49,7 @@ def tiny(bench, workload, traffic_name=None):
     traffic = copy.deepcopy(harness.load_json(
         harness.BENCH / "traffic" / f"{traffic_name or cell['traffic']}.json"))
     config["layer_dims"] = [64, 16, config["layer_dims"][-1]]
-    config["graph"] = {"nodes": 300, "undirected_edges": 700,
-                       "feature_nnz": 3000}
+    config["graph"] = cut_graph(config["graph"], 300, 700, 3000)
     if "pool" in traffic:
         traffic.update(pool=24, profile_graphs=32, warm_requests=16)
         traffic["graphs"]["nodes"]["max"] = 140
@@ -54,8 +67,9 @@ def run_tiny(bench, peak):
         config, traffic = tiny(bench, workload, traffic)
         if metrics is None:
             metrics = harness.metrics_for(bench, workload, False)
+        cell = harness.find(bench["workloads"], workload, "workload")
         return harness.measure(
             config, traffic, metrics, seed, seconds,
-            False, t_start=0.0, peak=peak,
+            False, chips=cell["chips"], t_start=0.0, peak=peak,
             require_compiled=lambda interpret: None)
     return go

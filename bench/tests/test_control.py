@@ -9,17 +9,17 @@ from bench import drivers, graphs, harness
 from bench.references import gcn as ref
 
 
-@pytest.mark.parametrize("workload", ["pubmed.full", "cora.full"])
+@pytest.mark.parametrize("workload", ["pubmed.full", "cora.full",
+                                      "pubmed.full_clustered"])
 def test_control_fails_whole_graph_limit(bench, workload):
-    from conftest import tiny
+    from conftest import cut_graph, tiny
     config, traffic = tiny(bench, workload)
     limit = harness.load_json(
         harness.ROOT / harness.find(bench["configs"], config["name"],
                                     "configuration")["file"]
     )["check"]["logit_gap"]
     config = dict(config)
-    config["graph"] = {"nodes": 2000, "undirected_edges": 5000,
-                       "feature_nnz": 30000}
+    config["graph"] = cut_graph(config["graph"], 2000, 5000, 30000)
     config["layer_dims"] = [256, 16, config["layer_dims"][-1]]
     seed = 5
     w = [np.asarray(x) for x in drivers.make_weights(seed, config["layer_dims"])]

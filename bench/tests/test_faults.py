@@ -9,7 +9,7 @@ exist in these one-chip cells."""
 import jax.numpy as jnp
 import pytest
 
-FULL = ["pubmed.full", "cora.full"]
+FULL = ["pubmed.full", "cora.full", "pubmed.full_clustered"]
 STREAM = ["pubmed.stream"]
 
 
@@ -84,17 +84,21 @@ def test_stale_answer_is_incorrect(monkeypatch, run_tiny, workload):
 
 @pytest.mark.parametrize("workload", FULL + STREAM)
 def test_blind_check_is_incorrect(monkeypatch, run_tiny, workload):
-    """A check that never flags: the injected fault goes unseen."""
+    """A check that never flags: the injected fault goes unseen.  Every
+    verdict comes from ``abft._tripped``; the report's compiled program
+    is traced afresh so that no earlier trace keeps the real one."""
+    import jax
+
     from repro.core import abft
 
-    monkeypatch.setattr(abft.Check, "flag",
-                        lambda self, cfg: jnp.zeros((), bool))
-    real = abft.Check.elementwise
+    monkeypatch.setattr(abft, "_tripped",
+                        lambda d, scale, cfg: jnp.zeros(jnp.shape(d), bool))
+    body = abft._summarize_compiled.__wrapped__
 
-    def blind(self, cfg):
-        f, rel = real(self, cfg)
-        return jnp.zeros_like(f), rel
-    monkeypatch.setattr(abft.Check, "elementwise", blind)
+    def fresh(checks, cfg, tagging):     # a new function: no cached trace
+        return body(checks, cfg, tagging)
+    monkeypatch.setattr(abft, "_summarize_compiled", jax.jit(
+        fresh, static_argnames=("cfg", "tagging")))
     out = run_tiny(workload)
     assert out["correct"] is False
     assert out["checks"]["fault_missed"]["value"] == 1

@@ -28,7 +28,7 @@ def _traced(bench, peak, workload, tmp_path, seconds):
     ctx = drivers.Ctx(config=config, traffic=traffic, seed=2**31 + 5,
                       seconds=seconds, trace_dir=str(tmp_path),
                       require_compiled=lambda interpret: None)
-    drivers.DRIVERS[traffic["driver"]](ctx)
+    harness.load_driver(traffic["driver"])(ctx)
     trace = trace_reduce.load(trace_reduce.find_trace_file(str(tmp_path)))
     return harness.Run(config=config, peak=peak, ctx=ctx, setup_time=0.0,
                        trace=trace)
@@ -37,7 +37,8 @@ def _traced(bench, peak, workload, tmp_path, seconds):
 def test_bench_lists_the_readers(bench):
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     for name in FULL:
-        assert per_layer[name]["workloads"] == ["pubmed.full", "cora.full"]
+        assert per_layer[name]["workloads"] == ["pubmed.full", "cora.full",
+                                                "pubmed.full_clustered"]
     for name in STREAM:
         assert per_layer[name]["workloads"] == ["pubmed.stream"]
 

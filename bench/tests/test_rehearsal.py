@@ -16,7 +16,8 @@ KEYS = ("correct", "attempted", "failed", "metrics", "checks")
 
 
 @pytest.mark.parametrize("workload", ["pubmed.full", "cora.full",
-                                      "pubmed.stream"])
+                                      "pubmed.stream",
+                                      "pubmed.full_clustered"])
 def test_cell_runs_and_is_correct(bench, run_tiny, workload):
     out = run_tiny(workload)
     for key in KEYS:
@@ -65,13 +66,60 @@ def test_command_refuses_without_a_tpu():
 def test_harness_names_no_cell_config_or_metric(bench):
     text = "".join((harness.BENCH / f).read_text()
                    for f in ("run.py", "harness.py"))
+    drivers = sorted((harness.BENCH / "drivers").glob("*.py"))
     names = ([c["name"] for c in bench["workloads"]]
              + [c["name"] for c in bench["configs"]]
-             + [m["name"] for m in bench["end_to_end"] + bench["per_layer"]])
+             + [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
+             + [f.stem for f in drivers if not f.stem.startswith("_")])
     for name in names:
         assert name not in text, name
     for flag in ("fused_layer", "fused_network", "vmem_budget", "interpret="):
-        assert flag not in (harness.BENCH / "drivers.py").read_text()
+        for f in drivers:
+            assert flag not in f.read_text(), (flag, f.name)
+
+
+ECHO = """
+def run(ctx):
+    ctx.counters["chips"] = ctx.chips
+    ctx.setup_end = ctx.clock()
+    ctx.window_s = ctx.seconds
+    ctx.attempted = 1
+    ctx.check("echo_gap", 0.0, 0.0)
+"""
+
+
+def test_a_driver_added_as_a_file_runs(monkeypatch, tmp_path, peak):
+    """A new cell brings its driver, traffic, configuration and metric as
+    new files; the harness finds them by name and hands the driver the
+    cell's chips."""
+    for d in ("drivers", "traffic", "metrics"):
+        (tmp_path / d).mkdir()
+    (tmp_path / "drivers" / "echo.py").write_text(ECHO)
+    (tmp_path / "traffic" / "echo_mix.json").write_text('{"driver": "echo"}')
+    (tmp_path / "metrics" / "echo_chips.py").write_text(
+        "def read(run):\n    return run.ctx.counters['chips']\n")
+    (tmp_path / "echo.json").write_text('{"name": "echo-config"}')
+    monkeypatch.setattr(harness, "BENCH", tmp_path)
+    monkeypatch.setattr(harness, "ROOT", tmp_path)
+    bench = {"configs": [{"name": "echo-config", "file": "echo.json"}],
+             "workloads": [{"name": "echo.cell", "config": "echo-config",
+                            "traffic": "echo_mix", "chips": 4}],
+             "end_to_end": [{"name": "echo_chips", "unit": "chips"}],
+             "per_layer": []}
+    out = harness.run_cell("echo.cell", 2**31 + 3, 0.1, False, bench=bench,
+                           t_start=0.0, peak=peak,
+                           require_compiled=lambda interpret: None)
+    assert out["correct"] is True
+    assert out["counters"]["chips"] == 4
+    assert out["metrics"]["echo_chips"] == {"value": 4.0, "unit": "chips"}
+
+
+@pytest.mark.parametrize("name", ["no_such_driver", "_stream",
+                                  "../harness", "sub/full_graph"])
+def test_unknown_or_private_driver_is_refused(name):
+    with pytest.raises((FileNotFoundError, ValueError)) as err:
+        harness.load_driver(name)
+    assert str(harness.BENCH / "drivers" / f"{name}.py") in str(err.value)
 
 
 def test_result_line_is_json(run_tiny):
