@@ -46,6 +46,7 @@ from .sharded import (  # noqa: F401
     Partition,
     sharded_gcn_fused,
     sharded_spmm_abft,
+    stage_rows,
 )
 from .streaming import (  # noqa: F401
     PackedRunner,

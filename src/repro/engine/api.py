@@ -55,7 +55,10 @@ class Graph:
     the optional offline column checksum e^T S (precompute once per static
     graph — computed once and auto-stashed back here on the first
     ``gcn_forward`` call when absent).  Dense ``s``/``h0`` may carry
-    leading batch axes (batched multi-graph serving).
+    leading batch axes (batched multi-graph serving).  On a partition,
+    ``h0`` may be held with its rows: padded to the stripes' rows and
+    sharded by rows (``engine.sharded.stage_rows``); every layer's output,
+    the logits too, then keeps those rows, the padding ones zero.
 
     The auto-stash assumes a *static* graph: it is invalidated when ``s``
     is rebound to a new object or the checksum dtype changes, but cannot

@@ -81,10 +81,10 @@ def backend_names() -> Tuple[str, ...]:
 
 def infer_backend(s: Any) -> str:
     """Map an adjacency operand to its natural backend name."""
-    from repro.kernels.spmm_abft.layout import BlockEll
+    from repro.kernels.spmm_abft.layout import BlockEll, BlockEllPlan
     from repro.engine.batching import PackedGraphs
     from jax.experimental import sparse as jsparse
-    if isinstance(s, (BlockEll, PackedGraphs)):
+    if isinstance(s, (BlockEll, BlockEllPlan, PackedGraphs)):
         return "block_ell"
     if isinstance(s, jsparse.BCOO):
         return "bcoo"
@@ -300,7 +300,17 @@ class BlockEllBackend(AggregationBackend):
     hook: the given layer's aggregation sweep perturbs one accumulator
     element mid-flight, in whichever kernel runs that layer (whole-
     network, fused single-layer, or the two-pass spmm — all three carry
-    the hook, so fallback paths are injectable too).
+    the hook, so fallback paths are injectable too).  On a partition the
+    stripe is global: only the shard that owns it perturbs it.
+
+    ``s`` may also be a :class:`~repro.kernels.spmm_abft.layout.
+    BlockEllPlan` (COO planned, tiles not built).  On a partition each
+    shard's stripes are then built and staged on their own
+    (:func:`~repro.engine.sharded.stage_rows`), so the host holds one
+    shard's tiles at a time; ``staged_bytes`` gives the bytes of tiles
+    and column indices on each device.  Features held with their rows
+    (the padded stripes' rows, sharded by rows over the partition) stay
+    so: only X and x_r cross devices, once a layer.
     """
 
     def __init__(self, s: Any, cfg: ABFTConfig, *,
@@ -311,7 +321,7 @@ class BlockEllBackend(AggregationBackend):
                  vmem_budget: Optional[int] = None,
                  granularity: Optional[str] = None,
                  inject: Optional[Tuple[int, int, int, float]] = None):
-        from repro.kernels.spmm_abft.layout import BlockEll, pad_block_rows
+        from repro.kernels.spmm_abft.layout import BlockEll, BlockEllPlan
         from repro.engine.batching import PackedGraphs
         self.cfg = cfg
         self.block_g = block_g
@@ -337,24 +347,35 @@ class BlockEllBackend(AggregationBackend):
             self.segments = jnp.asarray(s.stripe_graph)
             self.n_slots = s.n_slots
             s = s.bell
-        elif not isinstance(s, BlockEll):
-            raise TypeError("block_ell backend needs a BlockEll or "
-                            "PackedGraphs operand; convert with "
-                            "dense_to_block_ell/coo_to_block_ell or "
-                            "engine.batching.pack_graphs")
-        elif partition is not None:
-            s = pad_block_rows(s, partition.n_shards)
+        elif not isinstance(s, (BlockEll, BlockEllPlan)):
+            raise TypeError("block_ell backend needs a BlockEll, "
+                            "BlockEllPlan or PackedGraphs operand; convert "
+                            "with dense_to_block_ell/coo_to_block_ell/"
+                            "plan_block_ell or engine.batching.pack_graphs")
+        elif isinstance(s, BlockEllPlan) and partition is None:
+            s = s.build()
         self.bell = s
         if partition is None:
             from repro.kernels.spmm_abft.ops import device_block_ell
             self.cols, self.vals = device_block_ell(s)
         else:
-            # each shard's stripes go straight to their own device, never
-            # all through the default one
-            from repro.launch.mesh import GraphShardingRules
-            rules = GraphShardingRules(partition.mesh, partition.axis)
-            self.cols, self.vals = jax.device_put(
-                (s.block_cols, s.values), rules.block_ell_shardings())
+            # each shard's stripes are built and go straight to their own
+            # device, never all through the host or the default device
+            from .sharded import stage_rows
+            n = partition.n_shards
+            self.cols, self.vals = stage_rows(
+                partition, -(-s.n_block_rows // n) * n, s.tiles)
+
+    @property
+    def staged_bytes(self) -> Dict[int, int]:
+        """Bytes of the staged tile table (tiles and column indices) on
+        each device, by device id."""
+        out: Dict[int, int] = {}
+        for a in (self.cols, self.vals):
+            for shard in a.addressable_shards:
+                out[shard.device.id] = (out.get(shard.device.id, 0)
+                                        + shard.data.nbytes)
+        return out
 
     def _set_granularity(self, granularity: Optional[str], *, packed: bool):
         if granularity is None:
@@ -367,18 +388,14 @@ class BlockEllBackend(AggregationBackend):
         if granularity == "slot" and self.partition is not None:
             raise ValueError(
                 "granularity='slot' is not plumbed through the sharded "
-                "path (sharded_gcn_fused collapses each shard's partials "
-                "before the psum) — use granularity='stripe' there")
+                "paths (sharded_spmm_abft and sharded_gcn_fused keep one "
+                "partial per stripe, none per ell-slot) — use "
+                "granularity='stripe' there")
         self.granularity = _validate_granularity("block_ell", granularity,
                                                  supported)
 
     def _set_inject(self, inject):
         if inject is not None:
-            if self.partition is not None:
-                raise ValueError("inject= is not plumbed through the "
-                                 "sharded path (sharded_gcn_fused runs the "
-                                 "kernel without the hook) — injecting "
-                                 "there would silently run clean")
             if len(inject) != 4:
                 raise ValueError("inject is (layer, stripe, slot, delta); "
                                  f"got {inject!r}")
@@ -432,6 +449,14 @@ class BlockEllBackend(AggregationBackend):
         """
         if not self.fused_layer:
             return NotImplemented
+        from .sharded import rows_held
+        if self.partition is not None and rows_held(
+                h, self.partition, self.vals.shape[0] * self.vals.shape[2]):
+            # the fused sweep reads every H row on every shard: features
+            # held with their rows take the two-pass path, which moves
+            # only X across devices
+            self.fused_fallbacks += 1
+            return NotImplemented
         from repro.kernels.gcn_fused.ops import (
             FUSED_VMEM_BUDGET,
             fused_layer_fits,
@@ -472,7 +497,7 @@ class BlockEllBackend(AggregationBackend):
                                      w_r, self.partition,
                                      block_g=self.block_g,
                                      granularity=self.granularity,
-                                     interpret=self.interpret)
+                                     interpret=self.interpret, inject=inject)
 
     def network(self, h0, ws, wrs, cfg, *, stash=False):
         """Whole-network fusion (``kernels/gcn_fused``'s network kernel):
@@ -572,6 +597,7 @@ class BlockEllBackend(AggregationBackend):
         inject = None
         if self.inject is not None and self._layer_calls == self.inject[0]:
             inject = tuple(self.inject[1:])
+        layer = self._layer_calls
         self._layer_calls += 1
         if self.segments is not None:
             from repro.kernels.spmm_abft.ops import spmm_abft_packed
@@ -591,7 +617,7 @@ class BlockEllBackend(AggregationBackend):
         return sharded_spmm_abft(
             self.bell, self.cols, self.vals, x, xr_col, self.partition,
             block_g=self.block_g, granularity=gran,
-            interpret=self.interpret)
+            interpret=self.interpret, inject=inject, layer=layer)
 
 
 def make_backend(s: Any, cfg: ABFTConfig, *, backend: Optional[str] = None,

@@ -77,6 +77,22 @@ class BlockEll:
         n_bk = -(-self.shape[1] // self.block_k)
         return self.width / max(n_bk, 1)
 
+    def tiles(self, lo: int, hi: int) -> Tuple[np.ndarray, np.ndarray]:
+        """(block_cols, values) of stripes [lo, hi); stripes past the last
+        are padding, all-zero tiles aliasing column block 0, as
+        :func:`pad_block_rows` adds (only this run of stripes is copied)."""
+        top = max(min(hi, self.n_block_rows), lo)
+        block_cols = self.block_cols[lo:top]
+        values = self.values[lo:top]
+        add = hi - top
+        if add:
+            block_cols = np.concatenate(
+                [block_cols, np.zeros((add, self.width), np.int32)])
+            values = np.concatenate(
+                [values, np.zeros((add,) + self.values.shape[1:],
+                                  np.float32)])
+        return block_cols, values
+
     def todense(self) -> np.ndarray:
         """Dense [rows, cols] reconstruction (tests / small graphs only)."""
         m, k = self.shape
@@ -108,16 +124,8 @@ def pad_block_rows(bell: BlockEll, multiple: int) -> BlockEll:
     divide the mesh axis).  Padding stripes are all-zero tiles aliasing
     column-block 0 — they produce zero output rows and contribute nothing
     to either side of the check, so no masking anywhere downstream."""
-    nbm = bell.n_block_rows
-    add = (-nbm) % multiple
-    if add == 0:
-        return bell
-    values = np.concatenate(
-        [bell.values,
-         np.zeros((add,) + bell.values.shape[1:], np.float32)], axis=0)
-    block_cols = np.concatenate(
-        [bell.block_cols, np.zeros((add, bell.width), np.int32)], axis=0)
-    return BlockEll(values=values, block_cols=block_cols, shape=bell.shape)
+    return pad_block_rows_to(bell, -(-bell.n_block_rows // multiple)
+                             * multiple)
 
 
 def pad_width(bell: BlockEll, width_to: int) -> BlockEll:
@@ -150,17 +158,12 @@ def pad_block_rows_to(bell: BlockEll, n_block_rows: int) -> BlockEll:
     Unlike :func:`pad_block_rows` (round up to a multiple) this pins the
     stripe axis, so every batch of a canonical rung shares one jit shape.
     Padding stripes are the usual all-zero tiles aliasing column-block 0."""
-    add = n_block_rows - bell.n_block_rows
-    if add < 0:
+    if n_block_rows < bell.n_block_rows:
         raise ValueError(f"cannot pad {bell.n_block_rows} block rows down "
                          f"to {n_block_rows}")
-    if add == 0:
+    if n_block_rows == bell.n_block_rows:
         return bell
-    values = np.concatenate(
-        [bell.values,
-         np.zeros((add,) + bell.values.shape[1:], np.float32)], axis=0)
-    block_cols = np.concatenate(
-        [bell.block_cols, np.zeros((add, bell.width), np.int32)], axis=0)
+    block_cols, values = bell.tiles(0, n_block_rows)
     return BlockEll(values=values, block_cols=block_cols, shape=bell.shape)
 
 
@@ -205,41 +208,106 @@ def stack_block_ell(bells: Sequence[BlockEll],
     return BlockEll(values=values, block_cols=block_cols, shape=shape)
 
 
-def coo_to_block_ell(row: np.ndarray, col: np.ndarray, data: np.ndarray,
-                     shape: Tuple[int, int], block_m: int = 128,
-                     block_k: int = 128) -> BlockEll:
-    """Convert COO triplets to padded block-ELL (duplicates are summed)."""
+@dataclasses.dataclass(frozen=True)
+class BlockEllPlan:
+    """Where each nonzero of a COO matrix lands in its padded block-ELL
+    layout, with no tile built yet.
+
+    :meth:`tiles` builds any run of stripes on its own, at the matrix's
+    ELL width, so a table larger than one host buffer or one device is
+    built and staged a share at a time; :meth:`build` (what
+    :func:`coo_to_block_ell` returns) builds every stripe.  The plan has
+    BlockEll's layout properties, so code that reads only the layout
+    (``shape``, block sizes, ``width``, ``n_block_rows``,
+    ``padded_cols``) takes either.
+
+    The nonzeros are held in tile order (stripe, then column block), each
+    tile's own in their COO order: every tile element sums its duplicates
+    in the order the COO gives them."""
+
+    shape: Tuple[int, int]
+    block_m: int
+    block_k: int
+    width: int
+    stripe: np.ndarray          # [nnz] stripe of each nonzero, ascending
+    offset: np.ndarray          # [nnz] its place in its stripe's tiles
+    data: np.ndarray            # [nnz] float32 values
+    tile_stripe: np.ndarray     # [tiles] stripe of each stored tile
+    tile_slot: np.ndarray       # [tiles] its ELL slot
+    tile_col: np.ndarray        # [tiles] its column block
+
+    @property
+    def n_block_rows(self) -> int:
+        return -(-self.shape[0] // self.block_m)
+
+    @property
+    def padded_cols(self) -> int:
+        return (int(self.tile_col.max()) + 1) * self.block_k \
+            if self.tile_col.size else self.block_k
+
+    def tiles(self, lo: int, hi: int) -> Tuple[np.ndarray, np.ndarray]:
+        """(block_cols [hi-lo, width], values [hi-lo, width, bm, bk]) of
+        stripes [lo, hi).  Stripes past the last are padding: all-zero
+        tiles aliasing column block 0, as :func:`pad_block_rows` adds."""
+        n = hi - lo
+        tile = self.width * self.block_m * self.block_k
+        a, b = np.searchsorted(self.stripe, [lo, hi])
+        values = np.zeros(n * tile, np.float32)
+        np.add.at(values, (self.stripe[a:b] - lo) * tile + self.offset[a:b],
+                  self.data[a:b])
+        a, b = np.searchsorted(self.tile_stripe, [lo, hi])
+        block_cols = np.zeros((n, self.width), np.int32)
+        block_cols[self.tile_stripe[a:b] - lo, self.tile_slot[a:b]] = \
+            self.tile_col[a:b]
+        return block_cols, values.reshape(n, self.width, self.block_m,
+                                          self.block_k)
+
+    def build(self) -> BlockEll:
+        """Every stripe's tiles, in one host table."""
+        block_cols, values = self.tiles(0, self.n_block_rows)
+        return BlockEll(values=values, block_cols=block_cols,
+                        shape=self.shape)
+
+
+def plan_block_ell(row: np.ndarray, col: np.ndarray, data: np.ndarray,
+                   shape: Tuple[int, int], block_m: int = 128,
+                   block_k: int = 128) -> BlockEllPlan:
+    """Plan the padded block-ELL layout of COO triplets: each stripe
+    stores its nonzero tiles in ascending column-block order, padded to
+    the widest stripe."""
     m, k = shape
     row = np.asarray(row, np.int64)
     col = np.asarray(col, np.int64)
     data = np.asarray(data, np.float32)
-    nbm = -(-m // block_m)
     nbk = -(-k // block_k)
 
     br = row // block_m
     bc = col // block_k
-    tile_id = br * nbk + bc
-    order = np.argsort(tile_id, kind="stable")
-    tile_sorted = tile_id[order]
-    uniq, starts = np.unique(tile_sorted, return_index=True)
-    ends = np.append(starts[1:], tile_sorted.size)
+    order = np.argsort(br * nbk + bc, kind="stable")
+    tile_id = (br * nbk + bc)[order]
+    first = np.ones(tile_id.size, bool)
+    first[1:] = tile_id[1:] != tile_id[:-1]
+    uniq = tile_id[first]
+    which = np.cumsum(first) - 1               # each nonzero's tile
+    tile_stripe = uniq // nbk
+    tile_slot = (np.arange(uniq.size)
+                 - np.searchsorted(tile_stripe, tile_stripe, side="left"))
+    width = max(int(tile_slot.max()) + 1 if uniq.size else 1, 1)
+    local_r = row[order] - br[order] * block_m
+    local_c = col[order] - bc[order] * block_k
+    offset = (tile_slot[which] * block_m + local_r) * block_k + local_c
+    return BlockEllPlan(shape=(m, k), block_m=block_m, block_k=block_k,
+                        width=width, stripe=tile_stripe[which],
+                        offset=offset, data=data[order],
+                        tile_stripe=tile_stripe, tile_slot=tile_slot,
+                        tile_col=(uniq % nbk).astype(np.int32))
 
-    counts = np.zeros(nbm, np.int64)
-    np.add.at(counts, uniq // nbk, 1)
-    width = max(int(counts.max()) if counts.size else 1, 1)
 
-    values = np.zeros((nbm, width, block_m, block_k), np.float32)
-    block_cols = np.zeros((nbm, width), np.int32)
-    slot = np.zeros(nbm, np.int64)
-    for t, lo, hi in zip(uniq, starts, ends):
-        i, j = int(t // nbk), int(t % nbk)
-        s = int(slot[i])
-        idx = order[lo:hi]
-        np.add.at(values[i, s],
-                  (row[idx] - i * block_m, col[idx] - j * block_k), data[idx])
-        block_cols[i, s] = j
-        slot[i] += 1
-    return BlockEll(values=values, block_cols=block_cols, shape=(m, k))
+def coo_to_block_ell(row: np.ndarray, col: np.ndarray, data: np.ndarray,
+                     shape: Tuple[int, int], block_m: int = 128,
+                     block_k: int = 128) -> BlockEll:
+    """Convert COO triplets to padded block-ELL (duplicates are summed)."""
+    return plan_block_ell(row, col, data, shape, block_m, block_k).build()
 
 
 def dense_to_block_ell(a: np.ndarray, block_m: int = 128,

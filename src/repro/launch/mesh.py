@@ -227,9 +227,10 @@ class ShardingRules:
 # ---------------------------------------------------------------------------
 # Graph (GCN engine) sharding rules.  The block-ELL aggregation shards by
 # row-stripe: the tile table and its column-index table split on one mesh
-# axis, activations stay replicated (any stripe may gather any X row), and
-# the per-shard checksum partials psum into a replicated report — so the
-# only sharded tensors are the adjacency tiles and the output rows.
+# axis, the kernel's X operand is replicated (any stripe may gather any X
+# row), and the per-shard checksum partials psum into a replicated report.
+# Features held with their rows, and each layer's output, split by rows
+# like the stripes (engine/sharded.py).
 # ---------------------------------------------------------------------------
 
 def make_graph_mesh(n_devices: Optional[int] = None,
@@ -281,11 +282,6 @@ class GraphShardingRules:
         [nbm_local] partials stay on the stripe axis and concatenate into
         the global per-stripe vector instead of psum-collapsing."""
         return P(self.axis)
-
-    def block_ell_shardings(self) -> Tuple[NamedSharding, NamedSharding]:
-        """(cols, values) NamedShardings for device_put staging."""
-        return (NamedSharding(self.mesh, self.stripe_spec()),
-                NamedSharding(self.mesh, self.tile_spec()))
 
 
 def _p(p) -> str:

@@ -22,7 +22,8 @@ import jax
 # the whole vocabulary: a name outside it is refused, so readers, tests and
 # PERF.md point at one list
 SPANS = (
-    # engine (engine/api.py, engine/backends.py, kernels/spmm_abft/ops.py)
+    # engine (engine/api.py, engine/backends.py, engine/sharded.py,
+    # kernels/spmm_abft/ops.py)
     "gcn.forward",          # gcn_apply: the forward and its report; mode
     "gcn.layer",            # one layer of the loop, or the network hook; layer
     "gcn.combine",          # X = H W
@@ -30,6 +31,8 @@ SPANS = (
     "gcn.aggregate",        # the aggregation (and a fused layer's kernel)
     "gcn.corners",          # the eq.-6 corner reduction after the kernel
     "gcn.summarize",        # checks -> one report
+    "gcn.stage",            # one shard's rows to its devices; shard, bytes
+    "gcn.exchange",         # a sharded layer's gather of X, x_r; layer, bytes
     # serving loop (engine/streaming.py)
     "stream.seal",          # one bin sealed; batch, cause, graphs
     "stream.pack",          # pack_graphs of the sealed bin

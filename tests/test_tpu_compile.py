@@ -114,3 +114,40 @@ def test_gcn_network_compiles(one_chip, name, variant):
                                        ((2, p, p), jnp.float32),
                                        ((2, p, 1), jnp.float32)],
              **variant)
+
+
+@pytest.mark.parametrize("inject", [None, (257, 0, 4.0)],
+                         ids=["clean", "inject"])
+def test_sharded_aggregation_compiles_on_four_chips(topo, inject):
+    """NELL's widest layer on a 2x2 mesh: the all-gather of X and x_r,
+    then the stripe-sharded kernel and its psum'd corner, with the fault
+    hook on the shard that owns stripe 257.  Each chip's operands fit a
+    quarter of the table."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.engine import sharded
+
+    mesh = Mesh(np.asarray(topo.devices), ("graph",))
+    st = STATS["nell"]
+    nbm = -(-st.nodes // BLOCK)
+    stripes = -(-nbm // 4) * 4
+    rows, width, g = stripes * BLOCK, 410, st.classes
+
+    def arg(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+    gather = sharded._gather_program(mesh, "graph", 2).lower(
+        arg((rows, g), jnp.float32, "graph"),
+        arg((rows, 1), jnp.float32, "graph")).compile()
+    assert "all-gather" in gather.as_text()
+    program = sharded._spmm_program(mesh, "graph", nbm * BLOCK, BLOCK, BLOCK,
+                                    False, "layer", inject)
+    compiled = program.lower(
+        arg((stripes, width), jnp.int32, "graph"),
+        arg((stripes, width, BLOCK, BLOCK), jnp.float32, "graph"),
+        arg((rows, g), jnp.float32), arg((rows, 1), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    tiles = stripes // 4 * width * BLOCK * BLOCK * 4
+    assert compiled.memory_analysis().argument_size_in_bytes < 1.1 * tiles
