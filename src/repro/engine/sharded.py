@@ -171,7 +171,8 @@ def _spmm_program(mesh: Mesh, axis: str, padded_cols: int, block_k: int,
     def body(cols_l, vals_l, x_rep, xr_rep):
         def run(hook):
             return kernel.spmm_abft_kernel(cols_l, vals_l, x_rep, xr_rep,
-                                           interpret=interpret, inject=hook)
+                                           interpret=interpret, inject=hook,
+                                           block_g=block_g)
         return _partials(granularity, axis,
                          *_owned(run, inject, cols_l.shape[0], axis))
 
@@ -184,7 +185,7 @@ def _spmm_program(mesh: Mesh, axis: str, padded_cols: int, block_k: int,
 
     @jax.jit
     def program(cols, vals, x, xr):
-        xp, xrp = prepare_operands(layout, x, xr, block_g)
+        xp, xrp = prepare_operands(layout, x, xr)
         out, pred, actual = shard(cols, vals, xp, xrp)
         return out[:, :x.shape[1]], pred, actual
     return program

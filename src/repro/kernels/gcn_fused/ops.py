@@ -338,25 +338,28 @@ def gcn_network_layer(bell: BlockEll, h: Array, ws: Sequence[Array],
 def hbm_bytes_twopass(bell: BlockEll, f: int, g: int, *,
                       block_g: int = 128, itemsize: int = 4) -> int:
     """Modeled HBM bytes of one two-pass layer: the XLA combination pass
-    (read H and W, write X, plus the independent eq.-5 column H·w_r) then
-    the spmm_abft kernel pass (read S tiles + index table, read one X tile
-    and one x_r tile per stored slot, write out / sums / extra).
+    (read H and W, write X, plus the independent eq.-5 column H·w_r), the
+    fold of x_r into X's lane padding (read both, write the [K, G]
+    operand, G = g + 1 rounded up to lanes), then the spmm_abft kernel
+    pass (read S tiles + index table, read one operand tile per stored
+    slot, write out — check lane included — and sums).
 
     The tile count is the padded nbm × width table — ELL padding slots are
     scheduled like real tiles in both paths, so the comparison is fair.
     """
-    gp = _lanes(g, block_g)
+    gp = _lanes(g + 1, block_g)
     nbm, width = bell.n_block_rows, bell.width
     bm, bk = bell.block_m, bell.block_k
     tiles = nbm * width
     k_pad = max(bell.padded_cols, bell.block_k)
     n = bell.shape[0]
-    combine = n * f + f * g + k_pad * gp            # read H, W; write X
+    combine = n * f + f * g + k_pad * g             # read H, W; write X
     eq5 = n * f + f + k_pad                         # read H, w_r; write x_r
-    aggregate = (tiles * (bm * bk + bk * gp + bk)   # S, X, x_r tiles
+    fold = k_pad * (g + 1) + k_pad * gp             # read X, x_r; write both
+    aggregate = (tiles * (bm * bk + bk * gp)        # S, X|x_r tiles
                  + nbm * width                      # i32 index table ~ 1 word
-                 + nbm * bm * gp + nbm + nbm * bm)  # out, sums, extra
-    return itemsize * (combine + eq5 + aggregate)
+                 + nbm * bm * gp + nbm)             # out (+ check lane), sums
+    return itemsize * (combine + eq5 + fold + aggregate)
 
 
 def hbm_bytes_fused(bell: BlockEll, f: int, g: int, *,

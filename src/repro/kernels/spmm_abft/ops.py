@@ -1,5 +1,5 @@
 """Public wrappers for the spmm_abft Pallas kernel: host layout → device
-arrays, padding to block/lane multiples, final stripe-sum reduction, Check
+arrays, padding to block multiples, final stripe-sum reduction, Check
 construction, and the fused sparse GCN layer built on top of it.
 
 CPU has no Pallas TPU backend: pass ``interpret=True`` (tests do) or call
@@ -40,21 +40,17 @@ def fit_rows(x: jax.Array, rows: int) -> jax.Array:
 _fit_rows = fit_rows
 
 
-def prepare_operands(bell: BlockEll, x: jax.Array, xr: Optional[jax.Array],
-                     block_g: int) -> Tuple[jax.Array, jax.Array]:
+def prepare_operands(bell: BlockEll, x: jax.Array, xr: Optional[jax.Array]
+                     ) -> Tuple[jax.Array, jax.Array]:
     """The kernel's operand contract, shared by the single-device and the
     shard_map'd caller: rows padded to cover every referenced column
-    stripe (>= one block_k), the feature axis to a block_g lane multiple,
-    and ``xr`` defaulting to the standalone column X·e in f32."""
+    stripe (>= one block_k), the feature axis left at its logical width
+    (the kernel lays x_r into X's lane padding itself), and ``xr``
+    defaulting to the standalone column X·e in f32."""
     if xr is None:
         xr = x.astype(jnp.float32).sum(axis=1, keepdims=True)
     k_pad = max(bell.padded_cols, bell.block_k)
-    g = x.shape[1]
-    gp = -(-g // block_g) * block_g
-    xp = _fit_rows(x, k_pad)
-    if gp != g:
-        xp = jnp.pad(xp, [(0, 0), (0, gp - g)])
-    return xp, _fit_rows(xr.astype(jnp.float32), k_pad)
+    return _fit_rows(x, k_pad), _fit_rows(xr.astype(jnp.float32), k_pad)
 
 
 def trim_output(bell: BlockEll, out: jax.Array, g: int) -> jax.Array:
@@ -100,10 +96,10 @@ def spmm_abft(bell: BlockEll, x: jax.Array, xr: Optional[jax.Array] = None,
     n, _k_logical = bell.shape
     g = x.shape[1]
     cols, vals = _staged if _staged is not None else device_block_ell(bell)
-    xp, xrp = prepare_operands(bell, x, xr, block_g)
+    xp, xrp = prepare_operands(bell, x, xr)
     out, stripe_sums, extra = spmm_abft_kernel(cols, vals, xp, xrp,
                                                interpret=interpret,
-                                               inject=inject)
+                                               inject=inject, block_g=block_g)
     out = trim_output(bell, out, g)
     # the corners are the GCN check only where the caller carried the
     # eq.-5 column; an unchecked layer passes none and drops them
@@ -180,16 +176,13 @@ def spmm_abft_packed(cols: jax.Array, vals: jax.Array, x: jax.Array,
     Returns (out [rows, g], Check(predicted [G], actual [G]) | None).
     """
     validate_packed_operands(vals, x.shape[0], "x")
-    rows = x.shape[0]
-    g = x.shape[1]
-    gp = -(-g // block_g) * block_g
-    xp = jnp.pad(x, [(0, 0), (0, gp - g)]) if gp != g else x
+    rows, g = x.shape
     want_check = xr is not None
     xrp = (jnp.zeros((rows, 1), jnp.float32) if xr is None
            else xr.astype(jnp.float32))
-    out, stripe_sums, extra = spmm_abft_kernel(cols, vals, xp, xrp,
+    out, stripe_sums, extra = spmm_abft_kernel(cols, vals, x, xrp,
                                                interpret=interpret,
-                                               inject=inject)
+                                               inject=inject, block_g=block_g)
     out = out[:, :g]
     if not want_check:
         return out, None

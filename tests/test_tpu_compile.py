@@ -76,7 +76,10 @@ DATASETS = ["cora", "pubmed"]
                          ids=["clean", "inject"])
 @pytest.mark.parametrize("name", DATASETS)
 def test_spmm_abft_compiles(one_chip, name, inject):
-    nbm, width, _f, g = _widths(name)
+    """At the logical width the cells run: the kernel lays x_r into X's
+    lane padding itself."""
+    nbm, width, _f, _gp = _widths(name)
+    g = STATS[name].hidden
     _compile(spmm_abft_kernel, one_chip,
              _block_ell(nbm, width) + [((nbm * BLOCK, g), jnp.float32),
                                        ((nbm * BLOCK, 1), jnp.float32)],
