@@ -60,7 +60,7 @@ def run_mix(name: str, nodes, block: int, *, graphs: int, feat: int,
     from repro.engine.localize import surgical_slot_retry, \
         surgical_stripe_retry
     from repro.engine.streaming import (PackedRunner, make_packed_serve_step,
-                                        packed_step_args as _packed_args)
+                                        packed_step_args)
 
     cfg = ABFTConfig(mode="fused", threshold=1e-3, relative=True)
     stream = synth_graph_stream(graphs, n_lo=nodes[0], n_hi=nodes[1],
@@ -70,7 +70,7 @@ def run_mix(name: str, nodes, block: int, *, graphs: int, feat: int,
     params = fold_w_r(init_gcn(jax.random.PRNGKey(seed),
                                (feat, hidden, classes)), cfg)
     n_layers = len(params["layers"])
-    args = _packed_args(pb)
+    args = packed_step_args(pb)
     nbm = pb.bell.n_block_rows
     width = pb.bell.width
     bm = pb.block

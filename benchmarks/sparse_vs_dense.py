@@ -57,10 +57,9 @@ def run(csv: List[str]) -> None:
 
     from repro.core import ABFTConfig
     from repro.core.datasets import make_reduced
-    from repro.core.gcn import (dataset_to_dense, dataset_to_sparse,
-                                gcn_apply, gcn_apply_sparse, init_gcn,
-                                precompute_s_c)
+    from repro.core.gcn import dataset_to_dense, dataset_to_sparse, init_gcn
     from repro.core.opcount import gcn_op_counts
+    from repro.engine import Graph, gcn_apply, make_backend
 
     print("\n=== sparse vs dense: fused-check savings across sparsity ===")
     print(f"{'density':>9s} {'split M':>9s} {'fused M':>9s} {'savings%':>9s}")
@@ -87,10 +86,10 @@ def run(csv: List[str]) -> None:
         params = init_gcn(jax.random.PRNGKey(0), ds.stats.layer_dims)
         for mode in ("none", "split", "fused"):
             cfg = ABFTConfig(mode=mode)
-            s_c = precompute_s_c(s_sp, cfg) if cfg.enabled else None
-            f_dense = jax.jit(lambda p, s, x: gcn_apply(p, s, x, cfg))
+            s_c = make_backend(s_sp, cfg).s_c     # None with the check off
+            f_dense = jax.jit(lambda p, s, x: gcn_apply(p, Graph(s, x), cfg))
             f_sparse = jax.jit(
-                lambda p, s, x, sc: gcn_apply_sparse(p, s, x, cfg, sc))
+                lambda p, s, x, sc: gcn_apply(p, Graph(s, x, sc), cfg))
             t_d = _time(f_dense, params, s_d, h_d)
             t_s = _time(f_sparse, params, s_sp, h_sp, s_c)
             print(f"{ds.name:14s} {mode:6s} {t_d:10.1f} {t_s:10.1f} "

@@ -119,10 +119,10 @@ def run_jax_engine(csv: List[str], n_campaigns: int = 50,
     import jax.numpy as jnp
     import numpy.testing as npt
 
-    from repro.core.abft import ABFTConfig
+    from repro.core.abft import ABFTConfig, sparse_col_checksum
     from repro.core.datasets import make_reduced
     from repro.core.fault import NumpyGCN, flip_bit_f32, train_weights_numpy
-    from repro.core.gcn import dataset_to_sparse, precompute_s_c
+    from repro.core.gcn import dataset_to_sparse
     from repro.engine import Graph, gcn_forward, make_backend
 
     print(f"\n=== Table I smoke via JAX engine: {dataset} x{scale} "
@@ -133,7 +133,7 @@ def run_jax_engine(csv: List[str], n_campaigns: int = 50,
     s_sp, h_sp, _ = dataset_to_sparse(ds)
     params = {"layers": [{"w": jnp.asarray(w)} for w in ws]}
     cfg = ABFTConfig(mode="fused", threshold=tau, relative=False, kahan=True)
-    s_c = precompute_s_c(s_sp, cfg)
+    s_c = sparse_col_checksum(s_sp, cfg.dtype)
     logits, _ = gcn_forward(params, Graph(s=s_sp, h0=h_sp, s_c=s_c), cfg,
                             backend="bcoo")
     scale_l = max(1.0, float(np.abs(model.logits).max()))

@@ -111,10 +111,13 @@ def forward_phase(name: str) -> dict:
 
     from repro.core.abft import ABFTConfig
     from repro.engine import Graph, gcn_apply, make_backend
+    from repro.kernels.spmm_abft.layout import coo_to_block_ell
 
     t = time.perf_counter()
     ds, params, h0, ref = load_graph(name)
-    bell = ds.s.to_block_ell(BLOCK, BLOCK)
+    coo = ds.s
+    bell = coo_to_block_ell(coo.row, coo.col, coo.data, coo.shape, BLOCK,
+                            BLOCK)
     graph = Graph(s=bell, h0=jnp.asarray(h0))
     setup_s = time.perf_counter() - t
     n_layers = len(params["layers"])
@@ -267,12 +270,15 @@ def sharded_phase(n_chips: int) -> dict:
 
     from repro.core.abft import ABFTConfig, summarize
     from repro.engine import Graph, Partition, gcn_forward, make_backend
+    from repro.kernels.spmm_abft.layout import coo_to_block_ell
     from repro.launch.mesh import make_graph_mesh
 
     check(len(jax.devices()) >= n_chips,
           f"need {n_chips} chips, have {len(jax.devices())}")
     ds, params, h0, ref = load_graph("pubmed")
-    bell = ds.s.to_block_ell(BLOCK, BLOCK)
+    coo = ds.s
+    bell = coo_to_block_ell(coo.row, coo.col, coo.data, coo.shape, BLOCK,
+                            BLOCK)
     graph = Graph(s=bell, h0=jnp.asarray(h0))
     partition = Partition(make_graph_mesh(n_chips))
     cfg = ABFTConfig(mode="fused")

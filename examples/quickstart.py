@@ -10,17 +10,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import ABFTConfig, gcn_layer_fused, gcn_layer_split
+from repro.core import ABFTConfig
 from repro.core.datasets import make_reduced
-from repro.core.gcn import (
-    dataset_to_dense,
-    dataset_to_sparse,
-    gcn_apply,
-    gcn_apply_sparse,
-    init_gcn,
-    precompute_s_c,
-)
+from repro.core.gcn import dataset_to_dense, dataset_to_sparse, init_gcn
 from repro.core.opcount import gcn_op_counts
+from repro.engine import Graph, gcn_apply, gcn_layer, make_backend
+from repro.kernels.spmm_abft import dense_to_block_ell
 
 
 def main():
@@ -34,7 +29,7 @@ def main():
     for mode in ("split", "fused"):
         cfg = ABFTConfig(mode=mode, threshold=1e-3, relative=True)
         logits, report = jax.jit(
-            lambda p, s, h: gcn_apply(p, s, h, cfg))(params, s, h)
+            lambda p, s, h: gcn_apply(p, Graph(s, h), cfg))(params, s, h)
         print(f"{mode:6s}: clean forward  flag={bool(report.flag)} "
               f"max_rel={float(report.max_rel):.2e} "
               f"checks={int(report.n_checks)}")
@@ -42,7 +37,7 @@ def main():
     # inject a fault into the first layer's combination output
     w = params["layers"][0]["w"]
     cfg = ABFTConfig(mode="fused", threshold=1e-3, relative=True)
-    h_out, chk = gcn_layer_fused(s, h, w, cfg)
+    h_out, (chk,) = gcn_layer(make_backend(s, cfg), h, w, cfg)
     bad = h_out.at[5, 3].add(h_out.std() * 1e3)
     diff = abs(float(chk.predicted) - float(bad.sum()))
     print(f"\ninjected fault: |predicted - actual| = {diff:.3e} "
@@ -52,30 +47,25 @@ def main():
     # graphs — S stays a BCOO and s_c = e^T S is precomputed once offline
     cfg = ABFTConfig(mode="fused", threshold=1e-3, relative=True)
     s_sp, h_sp, _ = dataset_to_sparse(ds)
-    s_c = precompute_s_c(s_sp, cfg)
+    s_c = make_backend(s_sp, cfg).s_c
     logits_sp, rep_sp = jax.jit(
-        lambda p, s, x, sc: gcn_apply_sparse(p, s, x, cfg, sc)
+        lambda p, s, x, sc: gcn_apply(p, Graph(s, x, sc), cfg)
     )(params, s_sp, h_sp, s_c)
-    logits_d, _ = gcn_apply(params, s, h, cfg)
+    logits_d, _ = gcn_apply(params, Graph(s, h), cfg)
     err = float(jnp.abs(logits_sp - logits_d).max())
     print(f"\nsparse (BCOO) path: max |logit diff| vs dense = {err:.2e} "
           f"flag={bool(rep_sp.flag)}")
 
-    # unified engine: every backend behind one entry point — identical
-    # logits and report semantics from dense, BCOO, and the block-ELL
-    # Pallas kernel (repro.engine.gcn_apply; the core entry points above
-    # are thin compat shims over this).
-    from repro.engine import Graph, gcn_apply as engine_apply
-    from repro.kernels.spmm_abft import dense_to_block_ell
-
+    # every backend behind the one entry point — identical logits and
+    # report semantics from dense, BCOO, and the block-ELL Pallas kernel
     bell = dense_to_block_ell(s_np, block_m=32, block_k=32)
     print("\nunified engine, one entry point per backend:")
     for backend, graph in (("dense", Graph(s, h)),
                            ("bcoo", Graph(s_sp, h_sp, s_c=s_c)),
                            ("block_ell", Graph(bell, h))):
-        lg, rep = engine_apply(params, graph, cfg, backend=backend,
-                               **({"block_g": 32}
-                                  if backend == "block_ell" else {}))
+        lg, rep = gcn_apply(params, graph, cfg, backend=backend,
+                            **({"block_g": 32}
+                               if backend == "block_ell" else {}))
         err = float(jnp.abs(lg - logits_d).max())
         print(f"  {backend:9s} |logit diff|={err:.2e} "
               f"flag={bool(rep.flag)} checks={int(rep.n_checks)}")

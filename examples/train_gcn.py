@@ -12,7 +12,8 @@ import numpy as np
 
 from repro.core import ABFTConfig
 from repro.core.datasets import make_reduced
-from repro.core.gcn import dataset_to_dense, gcn_loss, init_gcn
+from repro.core.gcn import dataset_to_dense, init_gcn
+from repro.engine import Graph, gcn_loss
 from repro.optim import AdamWConfig, adamw_init, adamw_update, cosine_warmup
 from repro.runtime import ABFTGuard
 
@@ -38,7 +39,7 @@ def main():
     @jax.jit
     def step(state):
         (loss, report), grads = jax.value_and_grad(
-            lambda p: gcn_loss(p, s, h, y, None, abft), has_aux=True
+            lambda p: gcn_loss(p, Graph(s, h), y, None, abft), has_aux=True
         )(state["params"])
         lr = cosine_warmup(state["opt"]["step"], 20, args.steps)
         p2, o2 = adamw_update(state["params"], grads, state["opt"],

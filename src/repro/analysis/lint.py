@@ -172,7 +172,7 @@ def _build_traces(args) -> List[tuple]:
 
     if step == "gcn-train":
         from repro.core.abft import ABFTConfig
-        from repro.core.gcn import gcn_loss
+        from repro.engine import Graph, gcn_loss
 
         dims = [args.feat, args.hidden, args.classes]
         cfg = ABFTConfig(mode=args.mode)
@@ -185,7 +185,7 @@ def _build_traces(args) -> List[tuple]:
 
         def train(params, h0):
             (loss, rep), grads = jax.value_and_grad(
-                lambda p: gcn_loss(p, s, h0, labels, None, cfg),
+                lambda p: gcn_loss(p, Graph(s, h0), labels, None, cfg),
                 has_aux=True)(params)
             new = jax.tree.map(lambda p, g: p - 1e-2 * g, params, grads)
             return loss, rep.flag, new

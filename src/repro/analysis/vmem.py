@@ -1,20 +1,15 @@
-"""Static VMEM-budget model — the single source of truth.
+"""Static VMEM checks: the fused kernels' working-set model applied
+before anything compiles.
 
-This module owns the fused-kernel VMEM cost models that were born in
-``repro.kernels.gcn_fused.ops``.  They moved here so that the *runtime*
-fallback predicates (``fused_layer_fits`` / ``fused_network_fits``,
-consulted at trace time by ``engine/backends.py``) and the *static*
-checker (``abftlint --passes vmem``, run before anything compiles) are
-literally the same objects — ``repro.kernels.gcn_fused.ops`` re-exports
-them, and ``tests/test_abftlint.py`` asserts the identity.  A lint
-verdict of "fits" is therefore a guarantee about what the engine will
-decide, not a parallel model that can drift.
+The model itself (``fused_vmem_bytes`` / ``network_vmem_bytes`` and their
+``*_fits`` budget predicates) describes the fused kernels and lives with
+them in ``repro.kernels.gcn_fused.vmem``; this module imports those very
+objects (``tests/test_abftlint.py`` asserts the identity), so a lint
+verdict of "fits" is a guarantee about what the engine will decide, not a
+parallel model that can drift.
 
-Three layers of API, coarse to fine:
+Two passes, coarse to fine:
 
-* the analytic models (``fused_vmem_bytes`` / ``network_vmem_bytes``)
-  and their budget predicates — pure integer arithmetic on layer widths
-  and block shapes;
 * :func:`lint_rung_table` — evaluate every rung of a streaming
   ``RungTable`` against the budget for a given layer stack, *before*
   ``warmup()`` compiles anything;
@@ -22,9 +17,8 @@ Three layers of API, coarse to fine:
   any traced ``pallas_call``'s footprint directly from its BlockSpecs /
   grid, without executing, for kernels the analytic models don't know.
 
-Nothing here imports kernels or the engine at module level (they import
-*us*); jaxpr introspection imports are deferred into the functions that
-need them.
+Jaxpr introspection imports are deferred into the functions that need
+them.
 """
 from __future__ import annotations
 
@@ -32,69 +26,13 @@ import dataclasses
 import math
 from typing import Any, List, Optional, Sequence
 
-# Conservative per-core VMEM budget for the fused layer's resident + working
-# set.  Real TPU cores have ~16 MB; half of it leaves the scheduler slack
-# for double-buffered DMA and keeps the fallback decision robust across
-# generations.
-FUSED_VMEM_BUDGET = 8 * 1024 * 1024
-
-
-def _lanes(n: int, block_g: int) -> int:
-    return -(-n // block_g) * block_g
-
-
-def fused_vmem_bytes(f: int, g: int, bm: int, bk: int, *,
-                     block_g: int = 128, itemsize: int = 4) -> int:
-    """Model of the fused kernel's peak VMEM working set in bytes.
-
-    Resident across the grid: W [fp, gp] and w_r [fp, 1].  Per step,
-    double-buffered by the pipeline: the S tile [bm, bk] and the H tile
-    [bk, fp].  Plus the output block [bm, gp], the f32 accumulator scratch
-    [bm, gp], the extra-column scratch, and the recomputed x tile [bk, gp].
-    """
-    fp, gp = _lanes(f, block_g), _lanes(g, block_g)
-    resident = fp * gp + fp
-    streamed = 2 * (bm * bk + bk * fp)
-    working = 2 * bm * gp + bk * gp + bm * gp + 2 * bm
-    return itemsize * (resident + streamed + working)
-
-
-def fused_layer_fits(f: int, g: int, bm: int, bk: int, *,
-                     block_g: int = 128,
-                     budget: int = FUSED_VMEM_BUDGET) -> bool:
-    """True when the fused layer's working set fits the VMEM budget — the
-    engine falls back to the two-pass kernel otherwise (W too wide to stay
-    resident)."""
-    return fused_vmem_bytes(f, g, bm, bk, block_g=block_g) <= budget
-
-
-def network_vmem_bytes(dims: Sequence[int], bm: int, rows: int, *,
-                       block_g: int = 128, itemsize: int = 4) -> int:
-    """Model of the whole-network kernel's peak VMEM working set.
-
-    Dominant term: the two ping-pong activation buffers [rows, P] that keep
-    the whole activation matrix resident across layer boundaries (absent
-    for a single layer).  Resident per layer: one W slab [P, P] + w_r [P].
-    Per step, double-buffered: the S tile and (layer 0 only, but the
-    pipeline allocates it throughout) the H0 tile.  Plus the output block,
-    the f32 accumulator, the recomputed x tile, and the extra column.
-    """
-    p = _lanes(max(dims), block_g)
-    n_layers = len(dims) - 1
-    act = 2 * rows * p if n_layers > 1 else 0
-    resident = p * p + p
-    streamed = 2 * (bm * bm + bm * p)
-    working = 2 * bm * p + bm * p + bm * p + 2 * bm
-    return itemsize * (act + resident + streamed + working)
-
-
-def fused_network_fits(dims: Sequence[int], bm: int, rows: int, *,
-                       block_g: int = 128,
-                       budget: int = FUSED_VMEM_BUDGET) -> bool:
-    """True when the whole-network working set — activation ping-pong
-    buffers included — fits the VMEM budget; the engine falls back to
-    per-layer fused (then two-pass) otherwise."""
-    return network_vmem_bytes(dims, bm, rows, block_g=block_g) <= budget
+from repro.kernels.gcn_fused.vmem import (
+    FUSED_VMEM_BUDGET,
+    fused_layer_fits,
+    fused_network_fits,
+    fused_vmem_bytes,
+    network_vmem_bytes,
+)
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,9 @@
 
 Public surface:
   checksum  — checksum primitives (col/row/total, Kahan, fused-chain)
-  abft      — ABFTConfig + split/fused checks, GCN layer policies, reports
-  gcn       — JAX GCN model (Kipf & Welling) with ABFT threading
+  abft      — ABFTConfig + split/fused checks, CheckedOp, reports
+  gcn       — GCN parameters (Kipf & Welling) and normalized adjacency;
+              the checked forward is repro.engine's
   datasets  — synthetic stand-ins for Cora/Citeseer/PubMed/Nell
   opcount   — analytic op-count model (paper Table II)
   fault     — bit-flip fault-injection engine (paper Table I)
@@ -19,14 +20,8 @@ from .abft import (  # noqa: F401
     check_matmul,
     checked_matmul,
     fold_w_r_tree,
-    gcn_layer,
     per_op_report,
     resolve_w_r,
-    gcn_layer_fused,
-    gcn_layer_fused_sparse,
-    gcn_layer_sparse,
-    gcn_layer_split,
-    gcn_layer_split_sparse,
     merge_reports,
     sparse_col_checksum,
     sparse_matmul,

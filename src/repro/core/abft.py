@@ -397,37 +397,8 @@ def per_op_report(checks: Sequence[Optional[Check]], cfg: ABFTConfig, *,
 
 
 # ---------------------------------------------------------------------------
-# The paper's GCN layer checks, both dataflows.
-# ---------------------------------------------------------------------------
-
-def gcn_layer_split(s: Array, h: Array, w: Array, cfg: ABFTConfig
-                    ) -> tuple[Array, tuple[Check, Check]]:
-    """Baseline ABFT (eqs. 2–3): combination-first, two separate checks."""
-    return gcn_layer_split_sparse(s, h, w, cfg)
-
-
-def gcn_layer_fused(s: Array, h: Array, w: Array, cfg: ABFTConfig
-                    ) -> tuple[Array, Check]:
-    """GCN-ABFT (eqs. 4–6): single fused check s_c H w_r vs e^T H_out e.
-
-    H carries *no* check state: we only form w_r = W e (offline in a real
-    deployment), the extra column x_r = H w_r during the first multiply, and
-    s_c = e^T S (offline for static graphs).
-    """
-    return gcn_layer_fused_sparse(s, h, w, cfg)
-
-
-def gcn_layer(s: Array, h: Array, w: Array, cfg: ABFTConfig
-              ) -> tuple[Array, list[Check]]:
-    """Policy dispatch used by the GCN model."""
-    return gcn_layer_sparse(s, h, w, cfg)
-
-
-# ---------------------------------------------------------------------------
-# Canonical layer implementations, generic over the adjacency (BCOO or
-# dense S — the dense gcn_layer* wrappers above delegate here).  Only the
-# aggregation matmul and the s_c checksum honour sparsity.  For a static
-# graph s_c = e^T S never changes — compute it once offline
+# Adjacency helpers, generic over BCOO or dense S.  For a static graph
+# s_c = e^T S never changes — compute it once offline
 # (:func:`sparse_col_checksum`) and pass it to every layer/step.
 # ---------------------------------------------------------------------------
 
@@ -445,60 +416,13 @@ def sparse_col_checksum(s: Any, dtype: Any = jnp.float32) -> Array:
     """e^T S without densifying: O(nnz) segment-sum over column indices.
 
     This is the offline s_c precompute for static graphs — call it once per
-    graph and thread the result through :func:`gcn_layer_fused_sparse`.
+    graph and pass it to every layer (``engine.Graph(s_c=...)``).
     """
     if not _is_bcoo(s):
         return col_checksum(s, dtype)
     data = s.data.astype(dtype)
     cols = s.indices[..., 1]
     return jax.ops.segment_sum(data, cols, num_segments=s.shape[1])
-
-
-def _engine_layer(s: Any, h: Array, w: Array, cfg: ABFTConfig,
-                  s_c: Optional[Array], mode: str
-                  ) -> tuple[Array, list[Check]]:
-    """Delegate one layer to the unified engine under a forced mode.
-
-    The eq. 4–6 algebra formerly written out here lives in
-    ``repro/engine/api.py`` now; these entry points stay for callers that
-    address a single layer directly.  Imports are deferred: the engine
-    imports this module for Check/summarize.
-    """
-    from repro.engine import gcn_layer as engine_gcn_layer
-    from repro.engine import make_backend
-
-    if cfg.mode != mode:
-        cfg = dataclasses.replace(cfg, mode=mode)
-    bk = make_backend(s, cfg, s_c=s_c if cfg.enabled else None)
-    return engine_gcn_layer(bk, h, w, cfg)
-
-
-def gcn_layer_fused_sparse(s: Any, h: Array, w: Array, cfg: ABFTConfig,
-                           s_c: Optional[Array] = None
-                           ) -> tuple[Array, Check]:
-    """GCN-ABFT (eqs. 4–6) with a sparse (BCOO) aggregation operand.
-
-    Identical check algebra to :func:`gcn_layer_fused`; ``s_c`` should be
-    the offline precompute for static graphs (recomputed O(nnz) when not
-    supplied, which is still cheap but wasteful across layers/steps).
-    """
-    h_out, checks = _engine_layer(s, h, w, cfg, s_c, "fused")
-    return h_out, checks[0]
-
-
-def gcn_layer_split_sparse(s: Any, h: Array, w: Array, cfg: ABFTConfig,
-                           s_c: Optional[Array] = None
-                           ) -> tuple[Array, tuple[Check, Check]]:
-    """Baseline split ABFT (eqs. 2–3) over a sparse aggregation operand."""
-    h_out, checks = _engine_layer(s, h, w, cfg, s_c, "split")
-    return h_out, (checks[0], checks[1])
-
-
-def gcn_layer_sparse(s: Any, h: Array, w: Array, cfg: ABFTConfig,
-                     s_c: Optional[Array] = None
-                     ) -> tuple[Array, list[Check]]:
-    """Policy dispatch used by the sparse GCN model path."""
-    return _engine_layer(s, h, w, cfg, s_c, cfg.mode)
 
 
 # ---------------------------------------------------------------------------

@@ -127,12 +127,6 @@ class Coo:
         return jsparse.BCOO((jnp.asarray(self.data), jnp.asarray(idx)),
                             shape=self.shape)
 
-    def to_block_ell(self, block_m: int = 128, block_k: int = 128):
-        """Padded block-ELL layout for the spmm_abft Pallas kernel."""
-        from repro.kernels.spmm_abft.layout import coo_to_block_ell
-        return coo_to_block_ell(self.row, self.col, self.data, self.shape,
-                                block_m, block_k)
-
 
 @dataclasses.dataclass
 class GraphDataset:

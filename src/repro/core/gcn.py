@@ -1,25 +1,18 @@
-"""The paper's target model: a Kipf & Welling GCN with ABFT checking.
+"""The paper's target model: a Kipf & Welling GCN's parameters and its
+normalized adjacency D^-1/2 (A + I) D^-1/2, dense or BCOO.
 
-JAX path (this file): dense normalized adjacency — used by tests, examples
-and the pjit'd distributed demo on synthetic graphs.  The large-scale sparse
-realism (CSR, per-MAC fault injection) lives in the numpy engine
+The checked forward pass (eqs. 4–6 per layer, ReLU chain-breaking, the
+report) is ``repro.engine``'s: ``gcn_apply(params, Graph(s, h0), cfg)``.
+The per-MAC fault-injection model lives in the numpy engine
 (``core/fault.py``), matching the paper's accelerator-level evaluation.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from .abft import (
-    ABFTConfig,
-    ABFTReport,
-    Check,
-    sparse_col_checksum,
-    summarize,
-)
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -34,73 +27,6 @@ def init_gcn(rng: jax.Array, dims: Sequence[int]) -> Params:
         layers.append({"w": jax.random.uniform(k, (fin, fout), jnp.float32,
                                                -scale, scale)})
     return {"layers": layers}
-
-
-def gcn_forward(params: Params, s: Array, h0: Array, cfg: ABFTConfig
-                ) -> Tuple[Array, List[Check]]:
-    """Forward pass; checks are taken pre-activation (as in the paper).
-
-    Delegates to the adjacency-generic loop (dense S dispatches through
-    the same layer math; s_c is then computed once and shared by layers).
-    """
-    return gcn_forward_sparse(params, s, h0, cfg)
-
-
-def gcn_apply(params: Params, s: Array, h0: Array, cfg: ABFTConfig
-              ) -> Tuple[Array, ABFTReport]:
-    logits, checks = gcn_forward(params, s, h0, cfg)
-    return logits, summarize(checks, cfg)
-
-
-def gcn_loss(params: Params, s: Array, h0: Array, labels: Array,
-             mask: Optional[Array], cfg: ABFTConfig
-             ) -> Tuple[Array, ABFTReport]:
-    logits, report = gcn_apply(params, s, h0, cfg)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
-    if mask is not None:
-        nll = jnp.where(mask, nll, 0.0)
-        loss = nll.sum() / jnp.maximum(mask.sum(), 1)
-    else:
-        loss = nll.mean()
-    return loss, report
-
-
-# ---------------------------------------------------------------------------
-# Sparse-adjacency path.  S stays a BCOO; the per-graph s_c = e^T S is
-# computed once offline (:func:`precompute_s_c`) and reused across every
-# layer and step — the paper's "offline for static graphs" convention.
-# ---------------------------------------------------------------------------
-
-def precompute_s_c(s, cfg: ABFTConfig) -> Array:
-    """Offline e^T S in the checksum accumulation dtype."""
-    return sparse_col_checksum(s, cfg.dtype)
-
-
-def gcn_forward_sparse(params: Params, s, h0: Array, cfg: ABFTConfig,
-                       s_c: Optional[Array] = None
-                       ) -> Tuple[Array, List[Check]]:
-    """Forward loop, generic over the adjacency (BCOO or dense).
-
-    Thin shim over the unified engine (``repro.engine``), which owns the
-    canonical loop (ReLU chain-breaking, pre-activation checks) and the
-    backend dispatch; kept as the historical core entry point.
-    """
-    from repro.engine import Graph, gcn_forward as engine_forward
-    return engine_forward(params, Graph(s=s, h0=h0, s_c=s_c), cfg)
-
-
-def gcn_apply_sparse(params: Params, s, h0: Array, cfg: ABFTConfig,
-                     s_c: Optional[Array] = None
-                     ) -> Tuple[Array, ABFTReport]:
-    """Sparse twin of :func:`gcn_apply`: same logits, same report semantics.
-
-    ``s`` is a ``jax.experimental.sparse.BCOO`` normalized adjacency (dense
-    also accepted — the layer math dispatches).  BCOO is a pytree, so this
-    jits with ``s`` as a regular argument.
-    """
-    logits, checks = gcn_forward_sparse(params, s, h0, cfg, s_c)
-    return logits, summarize(checks, cfg)
 
 
 def normalized_adjacency_bcoo(edges: np.ndarray, n: int):

@@ -1,7 +1,8 @@
 """Unified checked-op engine: backend-dispatched layers, sharding, batching.
 
 Public surface:
-  api       — Graph, gcn_layer, gcn_forward, gcn_apply (the entry point)
+  api       — Graph, gcn_layer, gcn_forward, gcn_apply, gcn_loss (the one
+              entry point for a checked GCN)
   backends  — AggregationBackend (a CheckedOp) + dense/bcoo/block_ell registry
   sharded   — Partition + shard_map'd stripe-sharded block-ELL aggregation
   batching  — bucketed padding of variable-size graphs for batched serving
@@ -10,6 +11,8 @@ Public surface:
   lm        — guarded transformer LM serving (fold_lm_w_r, LMEngine)
   gat       — guarded GAT serving (attention-weighted aggregation under
               the same eq. 4–6 chain checks)
+  selfcheck — the serving loop's check-the-check: periodic re-derivation
+              of the eq.-5 fold and the staged s_c
 """
 from .api import (  # noqa: F401
     Graph,
@@ -17,6 +20,7 @@ from .api import (  # noqa: F401
     gcn_apply,
     gcn_forward,
     gcn_layer,
+    gcn_loss,
 )
 from .backends import (  # noqa: F401
     AggregationBackend,
@@ -47,6 +51,12 @@ from .sharded import (  # noqa: F401
     sharded_gcn_fused,
     sharded_spmm_abft,
     stage_rows,
+)
+from .selfcheck import (  # noqa: F401
+    CheckPathSelfCheck,
+    refold,
+    verify_s_c,
+    verify_w_r,
 )
 from .streaming import (  # noqa: F401
     PackedRunner,

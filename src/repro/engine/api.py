@@ -11,8 +11,9 @@ This module is the ONE place where the paper's check algebra lives:
     one linear chain, activations end it (paper §III);
   * report reduction: ``summarize`` / ``merge_reports`` from core.abft.
 
-``core/abft.py`` / ``core/gcn.py`` / ``kernels/spmm_abft/ops.py`` keep
-their historical entry points as thin shims over this engine.
+It is the only way in: every caller that runs a checked GCN builds a
+backend here (``make_backend``) and calls ``gcn_layer`` / ``gcn_forward``
+/ ``gcn_apply`` / ``gcn_loss``.
 
     logits, report = gcn_apply(params, Graph(s, h0), cfg,
                                backend="block_ell",
@@ -75,11 +76,6 @@ class Graph:
         return int(self.h0.shape[-2])
 
 
-# The per-layer right-checksum resolution (fold validation) is op-generic
-# and lives in core/abft.py now — kept under the historical name for the
-# localize/streaming callers that import it from here.
-_resolve_w_r = resolve_w_r
-
 
 def gcn_layer(bk: AggregationBackend, h: Array, w: Array, cfg: ABFTConfig,
               *, w_r: Optional[Array] = None, return_x: bool = False
@@ -107,7 +103,7 @@ def gcn_layer(bk: AggregationBackend, h: Array, w: Array, cfg: ABFTConfig,
     existed).  The stripe-surgical repair uses the stashed X to replay a
     two-pass layer's aggregation bit-for-bit.
     """
-    w_r = _resolve_w_r(w, w_r, cfg)
+    w_r = resolve_w_r(w, w_r, cfg)
     if cfg.mode != "split":
         fused = bk.layer(h, w, cfg, w_r=w_r)
         if fused is not NotImplemented:
@@ -211,7 +207,7 @@ def gcn_forward(params: Params, graph: Graph, cfg: ABFTConfig, *,
     layers = params["layers"]
     wrs: Optional[List[Optional[Array]]] = None
     if cfg.mode != "split":
-        wrs = [_resolve_w_r(layer["w"], layer.get("w_r"), cfg)
+        wrs = [resolve_w_r(layer["w"], layer.get("w_r"), cfg)
                for layer in layers]
         net = bk.network(h, [layer["w"] for layer in layers], wrs, cfg,
                          stash=return_intermediates)
@@ -262,3 +258,20 @@ def gcn_apply(params: Params, graph: Graph, cfg: ABFTConfig, *,
               else contextlib.nullcontext()):
             report = summarize(checks, cfg)
     return logits, report
+
+
+def gcn_loss(params: Params, graph: Graph, labels: Array,
+             mask: Optional[Array], cfg: ABFTConfig
+             ) -> Tuple[Array, ABFTReport]:
+    """Mean node-classification NLL over ``mask`` (all nodes when
+    ``None``), with the forward's report as the auxiliary output — for
+    ``jax.value_and_grad(..., has_aux=True)`` in a checked train step."""
+    logits, report = gcn_apply(params, graph, cfg)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
+    if mask is not None:
+        nll = jnp.where(mask, nll, 0.0)
+        loss = nll.sum() / jnp.maximum(mask.sum(), 1)
+    else:
+        loss = nll.mean()
+    return loss, report

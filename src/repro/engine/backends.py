@@ -30,7 +30,7 @@ single dispatch point for ``gcn_apply(..., backend=...)``.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -254,6 +254,43 @@ class BcooBackend(AggregationBackend):
         return h_out, Check(predicted=pred, actual=_total(h_out, self.cfg))
 
 
+def kernel_paths(dims: Sequence[int], block_m: int, block_k: int, rows: int,
+                 *, fused_layer: bool, fused_network: bool,
+                 block_g: int = 128, budget: Optional[int] = None
+                 ) -> Tuple[str, ...]:
+    """The kernel each layer of a ``dims = [f0, f1, ..., fL]`` stack takes
+    on the block-ELL path: ``"network"``, ``"fused"`` or ``"two_pass"``.
+
+    ``"network"`` for every layer when ``fused_network`` is on, the blocks
+    are square and the whole-network working set (two ping-pong buffers of
+    ``rows`` activation rows at the widest layer) fits ``budget``;
+    otherwise, layer by layer, ``"fused"`` when ``fused_layer`` is on and
+    that layer's [f, g] working set fits, else ``"two_pass"``.  ``budget``
+    defaults to ``FUSED_VMEM_BUDGET``.
+
+    The one home of the decision: the backend's trace-time hooks
+    (:meth:`BlockEllBackend.layer` / :meth:`~BlockEllBackend.network`),
+    the serving runner's eager counts and warnings
+    (``PackedRunner.fusion_counts``) and the stripe repair's replay
+    (``engine/localize.py``) all read it.  Only the fused/none check
+    modes consult it; split always runs two-pass.
+    """
+    from repro.kernels.gcn_fused.vmem import (FUSED_VMEM_BUDGET,
+                                              fused_layer_fits,
+                                              fused_network_fits)
+    if budget is None:
+        budget = FUSED_VMEM_BUDGET
+    dims = [int(d) for d in dims]
+    if fused_network and block_m == block_k and fused_network_fits(
+            dims, block_m, rows, block_g=block_g, budget=budget):
+        return ("network",) * (len(dims) - 1)
+    return tuple(
+        "fused" if fused_layer and fused_layer_fits(
+            f, g, block_m, block_k, block_g=block_g, budget=budget)
+        else "two_pass"
+        for f, g in zip(dims[:-1], dims[1:]))
+
+
 @register_backend("block_ell")
 class BlockEllBackend(AggregationBackend):
     """S as a host-side padded block-ELL (``kernels/spmm_abft/layout.py``);
@@ -440,6 +477,14 @@ class BlockEllBackend(AggregationBackend):
         bk._set_inject(inject)
         return bk
 
+    def _paths(self, dims, *, network: bool) -> Tuple[str, ...]:
+        """:func:`kernel_paths` at this backend's staged block shape."""
+        nbm, _width, bm, bk = self.vals.shape
+        return kernel_paths(dims, bm, bk, nbm * bm,
+                            fused_layer=self.fused_layer,
+                            fused_network=network, block_g=self.block_g,
+                            budget=self.vmem_budget)
+
     def layer(self, h, w, cfg, *, w_r=None):
         """Single-pass fused layer (``kernels/gcn_fused``): the combination
         H W is recomputed tile-by-tile inside the aggregation sweep with W
@@ -457,18 +502,9 @@ class BlockEllBackend(AggregationBackend):
             # only X across devices
             self.fused_fallbacks += 1
             return NotImplemented
-        from repro.kernels.gcn_fused.ops import (
-            FUSED_VMEM_BUDGET,
-            fused_layer_fits,
-            gcn_fused_layer,
-            gcn_fused_packed,
-        )
-        f, g = w.shape
-        bm, bk_ = self.vals.shape[2], self.vals.shape[3]
-        budget = FUSED_VMEM_BUDGET if self.vmem_budget is None \
-            else self.vmem_budget
-        if not fused_layer_fits(f, g, bm, bk_, block_g=self.block_g,
-                                budget=budget):
+        from repro.kernels.gcn_fused.ops import (gcn_fused_layer,
+                                                 gcn_fused_packed)
+        if self._paths(w.shape, network=False) != ("fused",):
             self.fused_fallbacks += 1
             return NotImplemented
         self.fused_hits += 1
@@ -513,19 +549,10 @@ class BlockEllBackend(AggregationBackend):
         """
         if not self.fused_network or self.partition is not None:
             return NotImplemented
-        from repro.kernels.gcn_fused.ops import (
-            FUSED_VMEM_BUDGET,
-            fused_network_fits,
-            gcn_network_layer,
-            gcn_network_packed,
-        )
-        nbm, _width, bm, bk_ = self.vals.shape
-        dims = [int(ws[0].shape[0])] + [int(w.shape[1]) for w in ws]
-        budget = FUSED_VMEM_BUDGET if self.vmem_budget is None \
-            else self.vmem_budget
-        if bm != bk_ or not fused_network_fits(dims, bm, nbm * bm,
-                                               block_g=self.block_g,
-                                               budget=budget):
+        from repro.kernels.gcn_fused.ops import (gcn_network_layer,
+                                                 gcn_network_packed)
+        dims = [ws[0].shape[0]] + [w.shape[1] for w in ws]
+        if self._paths(dims, network=True)[0] != "network":
             self.network_fallbacks += 1
             return NotImplemented
         self.network_hits += 1

@@ -60,6 +60,7 @@ import numpy as np
 
 from repro.core.abft import ABFTConfig
 from repro.core.checksum import row_checksum
+from repro.engine.backends import kernel_paths
 from repro.kernels.runtime import resolve_interpret
 from repro.kernels.spmm_abft.layout import BlockEll
 
@@ -137,9 +138,10 @@ def _recompute_stripes(bell: BlockEll, todo, w, w_r, h_ell, x_ell,
               @ jnp.asarray(w_r))[:, None]
         return spmm_abft(sub, jnp.asarray(x_ell), xr, block_g=block_g,
                          granularity="stripe", interpret=interpret)
-    from repro.kernels.gcn_fused.ops import fused_layer_fits, gcn_fused_layer
-    if not fused_layer_fits(*w.shape, bell.block_m, bell.block_k,
-                            block_g=block_g):
+    from repro.kernels.gcn_fused.ops import gcn_fused_layer
+    if kernel_paths(w.shape, bell.block_m, bell.block_k,
+                    bell.n_block_rows * bell.block_m, fused_layer=True,
+                    fused_network=False, block_g=block_g) != ("fused",):
         return None
     return gcn_fused_layer(sub, jnp.asarray(h_ell), w, w_r, block_g=block_g,
                            granularity="stripe", interpret=interpret)

@@ -20,6 +20,11 @@ which restores check integrity (data-path weight corruption remains the
 ordinary checks' job — and with a consistent refold it is invisible to
 ABFT by construction, which is exactly the consistent-corruption caveat
 the README documents).
+
+The streaming engine runs it on its own cadence
+(``StreamingEngine(selfcheck_interval=...)``); the chaos campaign
+(``repro.faults``) reads ``verify_w_r`` / ``verify_s_c`` to record its
+detections.
 """
 from __future__ import annotations
 
@@ -28,8 +33,10 @@ from typing import List, Optional
 
 import numpy as np
 
-from repro.core.abft import ABFTConfig
+from repro.core.abft import ABFTConfig, sparse_col_checksum
 from repro.core.checksum import row_checksum
+
+from .api import fold_w_r
 
 
 def _mismatch(a, b) -> bool:
@@ -61,13 +68,11 @@ def verify_s_c(graph, cfg: ABFTConfig) -> bool:
     stash diverges from e^T·S (corruption, or a stale stash)."""
     if not cfg.enabled or graph.s_c is None:
         return False
-    from repro.core.abft import sparse_col_checksum
     return _mismatch(sparse_col_checksum(graph.s, cfg.dtype), graph.s_c)
 
 
 def refold(params, cfg: ABFTConfig):
     """Rebuild every folded w_r from its source weights (the repair)."""
-    from repro.engine.api import fold_w_r
     return fold_w_r(params, cfg)
 
 

@@ -58,6 +58,41 @@ class Partition:
         return self.mesh.shape[self.axis]
 
 
+class GraphShardingRules:
+    """PartitionSpecs for the stripe-sharded block-ELL engine backend."""
+
+    def __init__(self, mesh: Mesh, axis: str = "graph"):
+        assert axis in mesh.axis_names, (axis, mesh.axis_names)
+        self.mesh = mesh
+        self.axis = axis
+
+    def stripe_spec(self) -> P:
+        """block_cols [nbm, width] — stripes over the graph axis."""
+        return P(self.axis)
+
+    def tile_spec(self) -> P:
+        """values [nbm, width, bm, bk] — stripes over the graph axis."""
+        return P(self.axis)
+
+    def activation_spec(self) -> P:
+        """X / x_r stay replicated: column blocks gather arbitrary rows."""
+        return P()
+
+    def out_spec(self) -> P:
+        """H_out rows live where their stripes live."""
+        return P(self.axis)
+
+    def report_spec(self) -> P:
+        """Checks psum to replicated scalars."""
+        return P()
+
+    def stripe_report_spec(self) -> P:
+        """Per-stripe check corners (granularity='stripe'): each shard's
+        [nbm_local] partials stay on the stripe axis and concatenate into
+        the global per-stripe vector instead of psum-collapsing."""
+        return P(self.axis)
+
+
 def stage_rows(partition: Partition, rows: int,
                build: Callable[[int, int], object]):
     """Arrays of ``rows`` leading rows, sharded by rows over the
@@ -163,7 +198,6 @@ def _spmm_program(mesh: Mesh, axis: str, padded_cols: int, block_k: int,
     the check partials."""
     from repro.kernels.spmm_abft import kernel
     from repro.kernels.spmm_abft.ops import prepare_operands
-    from repro.launch.mesh import GraphShardingRules
 
     rules = GraphShardingRules(mesh, axis)
     layout = types.SimpleNamespace(padded_cols=padded_cols, block_k=block_k)
@@ -198,7 +232,6 @@ def _fused_program(mesh: Mesh, axis: str, padded_cols: int, block_k: int,
                    inject: Optional[Tuple[int, int, float]]):
     from repro.kernels.gcn_fused import kernel
     from repro.kernels.gcn_fused.ops import prepare_fused_operands
-    from repro.launch.mesh import GraphShardingRules
 
     rules = GraphShardingRules(mesh, axis)
     layout = types.SimpleNamespace(padded_cols=padded_cols, block_k=block_k)

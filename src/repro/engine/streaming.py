@@ -57,10 +57,11 @@ import numpy as np
 from repro.core.abft import ABFTConfig, per_graph_report, \
     per_slot_report, per_stripe_report, summarize
 from repro.engine.api import Graph, fold_w_r, gcn_forward
-from repro.engine.backends import BlockEllBackend
+from repro.engine.backends import BlockEllBackend, kernel_paths
 from repro.kernels.runtime import resolve_interpret
 from repro.engine.batching import GraphBatch, PackedGraphs, \
     graph_pack_stats, pack_graphs
+from repro.engine.selfcheck import CheckPathSelfCheck
 from repro.runtime import ABFTGuard, GuardRefused
 from repro.runtime.spans import span
 
@@ -231,48 +232,42 @@ class PackedRunner:
                 inject=self.inject)
         return self._steps[key]
 
-    def _budget(self) -> int:
-        from repro.kernels.gcn_fused.ops import FUSED_VMEM_BUDGET
-        return FUSED_VMEM_BUDGET if self.vmem_budget is None \
-            else self.vmem_budget
-
-    def _network_dims(self) -> list:
+    def _paths(self, pb: PackedGraphs) -> Tuple[str, ...]:
+        """The kernel each layer takes for this packed shape
+        (:func:`~repro.engine.backends.kernel_paths`, the decision the
+        backend takes at trace time)."""
         layers = self.params["layers"]
-        return ([int(layers[0]["w"].shape[0])]
-                + [int(layer["w"].shape[1]) for layer in layers])
+        dims = ([layers[0]["w"].shape[0]]
+                + [layer["w"].shape[1] for layer in layers])
+        nbm, _w, bm, bk = pb.bell.values.shape
+        return kernel_paths(dims, bm, bk, nbm * bm,
+                            fused_layer=self.fused_layer,
+                            fused_network=self.fused_network,
+                            block_g=self.block_g, budget=self.vmem_budget)
 
     def fusion_counts(self, pb: PackedGraphs) -> Dict[str, int]:
-        """Per-batch fusion decisions, recomputed eagerly from the SAME
-        static shape predicates the backend evaluates at trace time — the
-        backend's own counters tick once per compile (the decision is
-        trace-time), which under-reports a serving run where every batch
-        takes the decision.  One whole-network hit subsumes the per-layer
-        decisions; a network fallback drops to the per-layer ladder, whose
-        hit/fallback split is evaluated layer by layer."""
-        from repro.kernels.gcn_fused.ops import fused_layer_fits, \
-            fused_network_fits
-
+        """Per-batch fusion decisions, read eagerly from the same
+        :func:`~repro.engine.backends.kernel_paths` the backend evaluates
+        at trace time — the backend's own counters tick once per compile
+        (the decision is trace-time), which under-reports a serving run
+        where every batch takes the decision.  One whole-network hit
+        subsumes the per-layer decisions; a network fallback drops to the
+        per-layer ladder, whose hit/fallback split is counted layer by
+        layer."""
         counts = {"fused_hits": 0, "fused_fallbacks": 0,
                   "network_hits": 0, "network_fallbacks": 0}
         if self.cfg.mode == "split":
             return counts
-        nbm, _w, bm, bk = pb.bell.values.shape
+        paths = self._paths(pb)
         if self.fused_network:
-            if bm == bk and fused_network_fits(self._network_dims(), bm,
-                                               nbm * bm,
-                                               block_g=self.block_g,
-                                               budget=self._budget()):
+            if paths[0] == "network":
                 counts["network_hits"] = 1
                 return counts
             counts["network_fallbacks"] = 1
         if self.fused_layer:
-            for layer in self.params["layers"]:
-                if fused_layer_fits(*layer["w"].shape, bm, bk,
-                                    block_g=self.block_g,
-                                    budget=self._budget()):
-                    counts["fused_hits"] += 1
-                else:
-                    counts["fused_fallbacks"] += 1
+            for path in paths:
+                counts["fused_hits" if path == "fused"
+                       else "fused_fallbacks"] += 1
         return counts
 
     def _warn_fallbacks(self, pb: PackedGraphs):
@@ -281,15 +276,9 @@ class PackedRunner:
         once per packed shape, from the layer widths we already know."""
         import warnings
 
-        from repro.kernels.gcn_fused.ops import fused_layer_fits, \
-            fused_network_fits
-
-        nbm, _w, bm, bk = pb.bell.values.shape
+        paths = self._paths(pb)
         if self.fused_network:
-            if bm == bk and fused_network_fits(self._network_dims(), bm,
-                                               nbm * bm,
-                                               block_g=self.block_g,
-                                               budget=self._budget()):
+            if paths[0] == "network":
                 return          # whole network fused; nothing falls back
             warnings.warn(
                 "--fused-network: the depth-wide working set (activation "
@@ -298,10 +287,9 @@ class PackedRunner:
                 "per-layer ladder instead")
         if not self.fused_layer:
             return
-        wide = [tuple(layer["w"].shape) for layer in self.params["layers"]
-                if not fused_layer_fits(*layer["w"].shape, bm, bk,
-                                        block_g=self.block_g,
-                                        budget=self._budget())]
+        wide = [tuple(layer["w"].shape)
+                for layer, path in zip(self.params["layers"], paths)
+                if path == "two_pass"]
         if wide:
             warnings.warn(
                 f"--fused-layer: layer widths {wide} exceed the fused VMEM "
@@ -556,7 +544,7 @@ class StreamingEngine:
     (``watchdog=``), with ``hang_timeout=`` forcing adjudication of a
     wedged in-flight batch from ``pump``.  ``selfcheck_interval=`` adds
     the check-the-check cadence: every N dispatches the folded ``w_r``
-    operands are re-derived bitwise (:mod:`repro.faults.selfcheck`) and
+    operands are re-derived bitwise (:mod:`repro.engine.selfcheck`) and
     a mismatch refolds + rebuilds the jitted steps.  ``inject=`` is the
     level-0 chaos hook (the kernel accumulator fault) — degraded levels
     are always built clean, which is what lets the ladder actually
@@ -633,7 +621,6 @@ class StreamingEngine:
                                            async_write=False)
         self._selfcheck = None
         if selfcheck_interval is not None:
-            from repro.faults.selfcheck import CheckPathSelfCheck
             self._selfcheck = CheckPathSelfCheck(cfg,
                                                  interval=selfcheck_interval)
         self.queue_capacity = queue_capacity

@@ -17,7 +17,7 @@ deterministic synthetic serving workload and classifies every step:
 * **false_positive** — flag with clean data.  Finite check-path
   corruption (``w_r``/``s_c``) lands here by construction: the data path
   is untouched, every verdict is a lie.  The periodic self-check
-  (:mod:`repro.faults.selfcheck`) is the defense, and the campaign
+  (:mod:`repro.engine.selfcheck`) is the defense, and the campaign
   records its detections separately.
 * **would-be false negative** — check-path corruption where the NAIVE
   comparison (``d > tau``: False for NaN) reports clean.  The shipped
@@ -55,7 +55,7 @@ from repro.faults.model import (
     lm_sweep_models,
     sweep_models,
 )
-from repro.faults.selfcheck import verify_s_c, verify_w_r
+from repro.engine.selfcheck import verify_s_c, verify_w_r
 from repro.runtime import ABFTGuard, GuardConfig, GuardRefused
 
 

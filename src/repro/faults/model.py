@@ -26,7 +26,7 @@ Sites (what the bits belong to):
 * ``w_r``         — the folded eq.-5 checksum-column source; corrupting
   it corrupts the carried column x_r = H·w_r, i.e. the CHECK path, not
   the data path.  Caught by the periodic self-check
-  (:mod:`repro.faults.selfcheck`).
+  (:mod:`repro.engine.selfcheck`).
 * ``s_c``         — the offline adjacency column checksum e^T·S (dense /
   BCOO serving path).  Check path again; self-check territory.
 

@@ -8,7 +8,6 @@ from .ops import (  # noqa: F401
     fused_layer_fits,
     fused_network_fits,
     fused_vmem_bytes,
-    gcn_fused_auto,
     gcn_fused_layer,
     gcn_fused_packed,
     gcn_network_layer,

@@ -45,8 +45,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.analysis.vmem import network_vmem_bytes
 from repro.kernels.spmm_abft.kernel import f32_dot
+
+from .vmem import network_vmem_bytes
 
 # Mosaic's default scoped-VMEM limit on v5e; a kernel whose working set
 # may exceed it must ask for more through ``vmem_limit_bytes``

@@ -13,14 +13,6 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.analysis.vmem import (  # noqa: F401  (re-exported: the runtime
-    FUSED_VMEM_BUDGET,             # fallback predicates and the abftlint
-    _lanes,                        # static checker are the SAME objects —
-    fused_layer_fits,              # see repro/analysis/vmem.py)
-    fused_network_fits,
-    fused_vmem_bytes,
-    network_vmem_bytes,
-)
 from repro.core.abft import Check
 from repro.kernels.spmm_abft.layout import BlockEll
 from repro.kernels.spmm_abft.ops import (
@@ -32,6 +24,14 @@ from repro.kernels.spmm_abft.ops import (
 )
 
 from .kernel import gcn_fused_kernel, gcn_network_kernel
+from .vmem import (  # noqa: F401  (re-exported beside the HBM models)
+    FUSED_VMEM_BUDGET,
+    _lanes,
+    fused_layer_fits,
+    fused_network_fits,
+    fused_vmem_bytes,
+    network_vmem_bytes,
+)
 
 Array = jax.Array
 
@@ -330,9 +330,9 @@ def gcn_network_layer(bell: BlockEll, h: Array, ws: Sequence[Array],
 # ---------------------------------------------------------------------------
 # Cost models: when is fusing the right call?  The VMEM working-set models
 # (fused_vmem_bytes / network_vmem_bytes and their *_fits predicates) live
-# in repro.analysis.vmem — imported above — so the static lint and this
-# runtime fallback share one model.  The HBM traffic models stay here:
-# they price a BlockEll layout, which the analysis layer doesn't know.
+# in vmem.py beside this module, where the engine's path decision and the
+# static lint both read them.  The HBM traffic models below price a
+# BlockEll layout.
 # ---------------------------------------------------------------------------
 
 def hbm_bytes_twopass(bell: BlockEll, f: int, g: int, *,
@@ -410,12 +410,3 @@ def hbm_bytes_network(bell: BlockEll, dims: Sequence[int], *,
         traffic += n_layers * rows * p
     return itemsize * traffic
 
-
-def gcn_fused_auto(bell: BlockEll, h: Array, w: Array,
-                   w_r: Optional[Array] = None, *, block_g: int = 128
-                   ) -> Tuple[Array, Optional[Check]]:
-    """Same as :func:`gcn_fused_layer`, interpret mode resolved by
-    :func:`repro.kernels.runtime.resolve_interpret`."""
-    from repro.kernels.runtime import resolve_interpret
-    return gcn_fused_layer(bell, h, w, w_r, block_g=block_g,
-                           interpret=resolve_interpret())

@@ -8,9 +8,7 @@ from .layout import (  # noqa: F401
     stack_block_ell,
 )
 from .ops import (  # noqa: F401
-    gcn_layer_fused_sparse_kernel,
     spmm_abft,
-    spmm_abft_auto,
     spmm_abft_packed,
     stripe_check_corners,
 )

@@ -1,9 +1,10 @@
 """Public wrappers for the spmm_abft Pallas kernel: host layout → device
-arrays, padding to block multiples, final stripe-sum reduction, Check
-construction, and the fused sparse GCN layer built on top of it.
+arrays, padding to block multiples, final stripe-sum reduction and Check
+construction.  A checked GCN layer over this kernel is the engine's
+``block_ell`` backend (``repro.engine``).
 
-CPU has no Pallas TPU backend: pass ``interpret=True`` (tests do) or call
-through :func:`spmm_abft_auto`, which falls back to interpret mode off-TPU.
+CPU has no Pallas TPU backend: pass ``interpret=True`` (tests do; the
+engine resolves it through ``kernels/runtime.resolve_interpret``).
 """
 from __future__ import annotations
 
@@ -37,8 +38,6 @@ def fit_rows(x: jax.Array, rows: int) -> jax.Array:
     return x
 
 
-_fit_rows = fit_rows
-
 
 def prepare_operands(bell: BlockEll, x: jax.Array, xr: Optional[jax.Array]
                      ) -> Tuple[jax.Array, jax.Array]:
@@ -50,7 +49,7 @@ def prepare_operands(bell: BlockEll, x: jax.Array, xr: Optional[jax.Array]
     if xr is None:
         xr = x.astype(jnp.float32).sum(axis=1, keepdims=True)
     k_pad = max(bell.padded_cols, bell.block_k)
-    return _fit_rows(x, k_pad), _fit_rows(xr.astype(jnp.float32), k_pad)
+    return fit_rows(x, k_pad), fit_rows(xr.astype(jnp.float32), k_pad)
 
 
 def trim_output(bell: BlockEll, out: jax.Array, g: int) -> jax.Array:
@@ -191,37 +190,3 @@ def spmm_abft_packed(cols: jax.Array, vals: jax.Array, x: jax.Array,
     return out, packed_check_corners(stripe_sums, extra, segments,
                                      num_segments)
 
-
-def spmm_abft_auto(bell: BlockEll, x: jax.Array,
-                   xr: Optional[jax.Array] = None, *, block_g: int = 128
-                   ) -> Tuple[jax.Array, Check]:
-    """Same as :func:`spmm_abft`, interpret mode resolved by
-    :func:`repro.kernels.runtime.resolve_interpret`."""
-    from repro.kernels.runtime import resolve_interpret
-    return spmm_abft(bell, x, xr, block_g=block_g,
-                     interpret=resolve_interpret())
-
-
-def gcn_layer_fused_sparse_kernel(bell: BlockEll, h: jax.Array, w: jax.Array,
-                                  *, w_r: Optional[jax.Array] = None,
-                                  block_g: int = 128,
-                                  interpret: bool = False
-                                  ) -> Tuple[jax.Array, Check]:
-    """One GCN layer H_out = S (H W) with the single fused GCN-ABFT check
-    (eqs. 4–6), aggregation through the block-ELL Pallas kernel.
-
-    Thin shim over the unified engine (``repro.engine``): the eq. 4–6
-    algebra lives in ``engine/api.py``; this backend only contributes the
-    kernel aggregation, whose fused epilogue carries x_r = H w_r so
-    Check.predicted = Σ S H w_r = s_c H w_r with no online s_c pass.
-    ``w_r`` (= W·e) is offline in a deployment — fold it at weight-load time.
-    """
-    from repro.core.abft import ABFTConfig
-    from repro.engine import gcn_layer, make_backend
-
-    cfg = ABFTConfig(mode="fused", dtype=jnp.float32)
-    bk = make_backend(bell, cfg, backend="block_ell", block_g=block_g,
-                      interpret=interpret)
-    w_r_vec = None if w_r is None else w_r.reshape(-1)
-    h_out, checks = gcn_layer(bk, h, w, cfg, w_r=w_r_vec)
-    return h_out, checks[0]

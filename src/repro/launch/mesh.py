@@ -225,12 +225,9 @@ class ShardingRules:
 
 
 # ---------------------------------------------------------------------------
-# Graph (GCN engine) sharding rules.  The block-ELL aggregation shards by
-# row-stripe: the tile table and its column-index table split on one mesh
-# axis, the kernel's X operand is replicated (any stripe may gather any X
-# row), and the per-shard checksum partials psum into a replicated report.
-# Features held with their rows, and each layer's output, split by rows
-# like the stripes (engine/sharded.py).
+# Graph (GCN engine) mesh.  The block-ELL aggregation shards by row-stripe
+# over one mesh axis; its PartitionSpecs live with the sharded engine
+# (engine/sharded.py::GraphShardingRules).
 # ---------------------------------------------------------------------------
 
 def make_graph_mesh(n_devices: Optional[int] = None,
@@ -243,45 +240,6 @@ def make_graph_mesh(n_devices: Optional[int] = None,
             f"need {n} devices for a {axis}={n} mesh, have {len(devs)} — run "
             f"under XLA_FLAGS=--xla_force_host_platform_device_count={n}")
     return Mesh(np.asarray(devs[:n]), (axis,))
-
-
-class GraphShardingRules:
-    """PartitionSpecs for the stripe-sharded block-ELL engine backend."""
-
-    def __init__(self, mesh: Mesh, axis: str = "graph"):
-        assert axis in mesh.axis_names, (axis, mesh.axis_names)
-        self.mesh = mesh
-        self.axis = axis
-
-    @property
-    def n_shards(self) -> int:
-        return self.mesh.shape[self.axis]
-
-    def stripe_spec(self) -> P:
-        """block_cols [nbm, width] — stripes over the graph axis."""
-        return P(self.axis)
-
-    def tile_spec(self) -> P:
-        """values [nbm, width, bm, bk] — stripes over the graph axis."""
-        return P(self.axis)
-
-    def activation_spec(self) -> P:
-        """X / x_r stay replicated: column blocks gather arbitrary rows."""
-        return P()
-
-    def out_spec(self) -> P:
-        """H_out rows live where their stripes live."""
-        return P(self.axis)
-
-    def report_spec(self) -> P:
-        """Checks psum to replicated scalars."""
-        return P()
-
-    def stripe_report_spec(self) -> P:
-        """Per-stripe check corners (granularity='stripe'): each shard's
-        [nbm_local] partials stay on the stripe axis and concatenate into
-        the global per-stripe vector instead of psum-collapsing."""
-        return P(self.axis)
 
 
 def _p(p) -> str:

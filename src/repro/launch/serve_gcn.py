@@ -55,7 +55,6 @@ from repro.engine import GraphBatch, PackedGraphs, fold_w_r, \
 from repro.engine.streaming import (
     PackedRunner,
     dense_retry_fn,
-    make_packed_serve_step,
     make_serve_step,
     packed_step_args,
 )
@@ -63,12 +62,6 @@ from repro.kernels.runtime import use_compile_cache
 from repro.runtime import ABFTGuard
 
 Batch = Union[GraphBatch, PackedGraphs]
-
-# long-standing private aliases, kept for callers that grew around the
-# pre-streaming layout (benchmarks/localization.py, external notebooks)
-_PackedRunner = PackedRunner
-_packed_args = packed_step_args
-_dense_retry_fn = dense_retry_fn
 
 
 def serve(batches: Sequence[Batch], params, cfg: ABFTConfig,

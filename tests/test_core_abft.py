@@ -16,8 +16,6 @@ from repro.core import (
     check_chain,
     check_matmul,
     checked_matmul,
-    gcn_layer_fused,
-    gcn_layer_split,
     fused_chain_checksum,
     kahan_total,
     predicted_matmul_checksum,
@@ -25,6 +23,7 @@ from repro.core import (
 )
 from repro.core.abft import report_traces
 from repro.core.checksum import col_checksum, row_checksum, total_checksum
+from repro.engine import gcn_layer, make_backend
 
 CFG = ABFTConfig(mode="fused", threshold=1e-3, relative=True)
 
@@ -110,10 +109,10 @@ def test_gcn_layer_detects_output_corruption(mode):
     w = rand((24, 16), 2)
     cfg = ABFTConfig(mode=mode, threshold=1e-3, relative=True)
     if mode == "split":
-        h_out, checks = gcn_layer_split(s, h, w, cfg)
+        h_out, checks = gcn_layer(make_backend(s, cfg), h, w, cfg)
         checks = list(checks)
     else:
-        h_out, chk = gcn_layer_fused(s, h, w, cfg)
+        h_out, (chk,) = gcn_layer(make_backend(s, cfg), h, w, cfg)
         checks = [chk]
     assert not bool(summarize(checks, cfg).flag)
 
@@ -131,8 +130,9 @@ def test_split_and_fused_agree_on_final_prediction():
     s = jnp.abs(rand((20, 20), 3)) / 20
     h = rand((20, 12), 4)
     w = rand((12, 8), 5)
-    _, (c1, c2) = gcn_layer_split(s, h, w, CFG)
-    _, cf = gcn_layer_fused(s, h, w, CFG)
+    split = dataclasses.replace(CFG, mode="split")
+    _, (c1, c2) = gcn_layer(make_backend(s, split), h, w, split)
+    _, (cf,) = gcn_layer(make_backend(s, CFG), h, w, CFG)
     np.testing.assert_allclose(c2.predicted, cf.predicted, rtol=1e-6)
 
 
@@ -279,7 +279,8 @@ def test_summarize_traces_once_per_check_structure():
 # ---------------------------------------------------------------------------
 
 def test_gcn_apply_and_grad():
-    from repro.core.gcn import gcn_apply, gcn_loss, init_gcn
+    from repro.core.gcn import init_gcn
+    from repro.engine import Graph, gcn_apply, gcn_loss
     n, f, h, c = 40, 12, 8, 4
     rng = np.random.default_rng(0)
     s = jnp.asarray(np.abs(rng.normal(size=(n, n))).astype(np.float32) / n)
@@ -287,13 +288,14 @@ def test_gcn_apply_and_grad():
     labels = jnp.asarray(rng.integers(0, c, size=n))
     params = init_gcn(jax.random.PRNGKey(0), (f, h, c))
     logits, report = jax.jit(
-        lambda p: gcn_apply(p, s, x0, CFG))(params)
+        lambda p: gcn_apply(p, Graph(s, x0), CFG))(params)
     assert logits.shape == (n, c)
     assert not bool(report.flag)
     assert np.isfinite(np.asarray(logits)).all()
 
     (loss, rep), grads = jax.value_and_grad(
-        lambda p: gcn_loss(p, s, x0, labels, None, CFG), has_aux=True)(params)
+        lambda p: gcn_loss(p, Graph(s, x0), labels, None, CFG),
+        has_aux=True)(params)
     assert np.isfinite(float(loss))
     flat = jax.tree.leaves(grads)
     assert all(np.isfinite(np.asarray(g)).all() for g in flat)

@@ -224,7 +224,8 @@ def test_hang_timeout_flushes_inflight_batch():
 # ---------------------------------------------------------------------------
 
 def test_engine_selfcheck_repairs_corrupted_fold_midstream():
-    from repro.faults import FaultInjector, FaultModel, verify_w_r
+    from repro.engine import verify_w_r
+    from repro.faults import FaultInjector, FaultModel
     stream = _stream(12)
     engine = _engine(stream, selfcheck_interval=1)
     # corrupt the carried eq.-5 fold in place mid-stream (a NaN stuck-at:

@@ -24,16 +24,14 @@ import numpy as np
 import pytest
 
 from repro.core.abft import ABFTConfig, Check
+from repro.engine import CheckPathSelfCheck, verify_s_c, verify_w_r
 from repro.faults import (
     CHECK_PATH_SITES,
-    CheckPathSelfCheck,
     FaultInjector,
     FaultModel,
     flip_bits,
     run_fault_campaign,
     sweep_models,
-    verify_s_c,
-    verify_w_r,
 )
 
 

@@ -12,10 +12,8 @@ from repro.kernels.matmul_abft.ref import matmul_abft_ref
 from repro.kernels.flash_checksum.ops import flash_attention_checksum
 from repro.kernels.flash_checksum.ref import flash_checksum_ref
 from repro.kernels.spmm_abft.layout import coo_to_block_ell, dense_to_block_ell
-from repro.kernels.spmm_abft.ops import (
-    gcn_layer_fused_sparse_kernel,
-    spmm_abft,
-)
+from repro.engine import gcn_layer, make_backend
+from repro.kernels.spmm_abft.ops import spmm_abft
 from repro.kernels.spmm_abft.ref import spmm_abft_ref
 
 CFG = ABFTConfig(mode="fused", threshold=1e-2, relative=True)
@@ -184,8 +182,10 @@ def test_spmm_abft_carried_column_chain():
     h = rnd(8, (n, f), jnp.float32) * 0.3
     w = rnd(9, (f, g), jnp.float32)
 
-    h_out, chk = gcn_layer_fused_sparse_kernel(bell, h, w, interpret=True,
-                                               block_g=32)
+    cfg = ABFTConfig(mode="fused", dtype=jnp.float32)
+    bk = make_backend(bell, cfg, backend="block_ell", block_g=32,
+                      interpret=True)
+    h_out, (chk,) = gcn_layer(bk, h, w, cfg)
     ref = dense @ np.asarray(h @ w)
     np.testing.assert_allclose(np.asarray(h_out), ref, rtol=1e-5, atol=1e-5)
     s_c = dense.astype(np.float64).sum(axis=0)

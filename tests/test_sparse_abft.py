@@ -3,8 +3,9 @@
 Three acceptance properties (ISSUE 1):
   (a) fused check_chain prediction == split-check composition on random
       matrix chains, within accumulation tolerance;
-  (b) gcn_apply_sparse (BCOO aggregation) logits == dense gcn_apply on
-      random graphs (atol 1e-4), clean runs unflagged in both;
+  (b) the engine's gcn_apply over a BCOO S (bcoo backend) gives the
+      dense backend's logits on random graphs (atol 1e-4), clean runs
+      unflagged in both;
   (c) a single injected fault in the SpMM output trips the fused check at
       the paper's Table I absolute thresholds (parity with core/fault.py's
       bit-flip model).
@@ -22,7 +23,6 @@ from repro.core import (
     ABFTConfig,
     check_chain,
     check_matmul,
-    gcn_layer_fused_sparse,
     sparse_col_checksum,
 )
 from repro.core.datasets import make_reduced
@@ -30,13 +30,11 @@ from repro.core.fault import THRESHOLDS, flip_bit_f32
 from repro.core.gcn import (
     dataset_to_dense,
     dataset_to_sparse,
-    gcn_apply,
-    gcn_apply_sparse,
     init_gcn,
     normalized_adjacency_bcoo,
     normalized_adjacency_dense,
-    precompute_s_c,
 )
+from repro.engine import Graph, gcn_apply, gcn_layer, make_backend
 from repro.kernels.spmm_abft import dense_to_block_ell, spmm_abft
 
 try:
@@ -110,10 +108,10 @@ def _parity_property(seed, n, f, h, c, mode):
     params = init_gcn(jax.random.PRNGKey(seed), (f, h, c))
     cfg = ABFTConfig(mode=mode, threshold=1e-3, relative=True)
 
-    logits_d, rep_d = gcn_apply(params, s_dense, h0, cfg)
-    s_c = precompute_s_c(s_bcoo, cfg) if cfg.enabled else None
+    logits_d, rep_d = gcn_apply(params, Graph(s_dense, h0), cfg)
+    s_c = make_backend(s_bcoo, cfg).s_c     # None with the check off
     logits_s, rep_s = jax.jit(
-        lambda p, s, x, sc: gcn_apply_sparse(p, s, x, cfg, sc)
+        lambda p, s, x, sc: gcn_apply(p, Graph(s, x, sc), cfg)
     )(params, s_bcoo, h0, s_c)
 
     np.testing.assert_allclose(np.asarray(logits_s), np.asarray(logits_d),
@@ -136,10 +134,11 @@ def test_dataset_sparse_matches_dense():
     s_np, h_np, _ = dataset_to_dense(ds)
     s_sp, h_sp, _ = dataset_to_sparse(ds)
     params = init_gcn(jax.random.PRNGKey(0), ds.stats.layer_dims)
-    logits_d, _ = gcn_apply(params, jnp.asarray(s_np), jnp.asarray(h_np), CFG)
-    s_c = precompute_s_c(s_sp, CFG)
+    logits_d, _ = gcn_apply(params, Graph(jnp.asarray(s_np),
+                                          jnp.asarray(h_np)), CFG)
+    s_c = make_backend(s_sp, CFG).s_c
     logits_s, rep = jax.jit(
-        lambda p, s, x, sc: gcn_apply_sparse(p, s, x, CFG, sc)
+        lambda p, s, x, sc: gcn_apply(p, Graph(s, x, sc), CFG)
     )(params, s_sp, h_sp, s_c)
     np.testing.assert_allclose(np.asarray(logits_s), np.asarray(logits_d),
                                atol=1e-4, rtol=1e-4)
@@ -149,7 +148,7 @@ def test_dataset_sparse_matches_dense():
 def test_offline_s_c_matches_online():
     ds = make_reduced("citeseer", scale=8, seed=1)
     s_sp, _, _ = dataset_to_sparse(ds)
-    offline = precompute_s_c(s_sp, CFG)
+    offline = make_backend(s_sp, CFG).s_c
     online = sparse_col_checksum(s_sp, CFG.dtype)
     np.testing.assert_allclose(np.asarray(offline), np.asarray(online))
     # and both equal the numpy fault engine's f64 s_c within f32 tolerance
@@ -221,8 +220,8 @@ def test_fused_sparse_layer_detects_fault():
     ds = make_reduced("cora", scale=8, seed=2)
     s_sp, h_sp, _ = dataset_to_sparse(ds)
     params = init_gcn(jax.random.PRNGKey(2), ds.stats.layer_dims)
-    h_out, chk = gcn_layer_fused_sparse(s_sp, h_sp,
-                                        params["layers"][0]["w"], CFG)
+    h_out, (chk,) = gcn_layer(make_backend(s_sp, CFG), h_sp,
+                              params["layers"][0]["w"], CFG)
     bad = np.asarray(h_out).astype(np.float64)
     bad[11, 7] += 10.0 * max(float(np.abs(bad).max()), 1.0)
     div = abs(float(chk.predicted) - float(bad.sum()))

@@ -44,7 +44,7 @@ from repro.engine import (
     synth_graph_stream,
 )
 from repro.engine.localize import surgical_stripe_retry
-from repro.launch.serve_gcn import _packed_args, make_packed_serve_step
+from repro.engine.streaming import make_packed_serve_step, packed_step_args
 from repro.runtime import ABFTGuard, GuardConfig
 
 
@@ -150,7 +150,7 @@ def test_inject_fires_on_two_pass_path():
     step = make_packed_serve_step(params, cfg, pb.n_slots, block_g=16,
                                   granularity="stripe",
                                   inject=(1, 0, 0, 64.0))
-    _, m = step(*_packed_args(pb))
+    _, m = step(*packed_step_args(pb))
     sf = np.asarray(m["abft_stripe_flags"])
     assert sf.sum() == 1 and sf[1, 0], np.argwhere(sf).tolist()
 
@@ -185,7 +185,7 @@ def test_fault_sweep_localizes_and_repairs_bit_for_bit():
     pb = pack_graphs(stream, block=16)
     cfg = _cfg()
     params = fold_w_r(init_gcn(jax.random.PRNGKey(5), (8, 8, 3)), cfg)
-    args = _packed_args(pb)
+    args = packed_step_args(pb)
 
     clean_step = make_packed_serve_step(params, cfg, pb.n_slots,
                                         block_g=16, fused_layer=True,
@@ -237,7 +237,7 @@ def test_surgical_rows_strictly_below_graph_retry():
     pb = pack_graphs(stream, block=16)
     cfg = _cfg()
     params = fold_w_r(init_gcn(jax.random.PRNGKey(7), (8, 8, 3)), cfg)
-    args = _packed_args(pb)
+    args = packed_step_args(pb)
     stripe_graph = np.asarray(pb.stripe_graph)
     n_layers = len(params["layers"])
     for layer in range(n_layers):
